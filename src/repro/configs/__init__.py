@@ -53,6 +53,27 @@ def get_config(name: str, **overrides) -> ModelConfig:
     return cfg
 
 
+def for_platform(cfg: ModelConfig) -> ModelConfig:
+    """``cfg`` with its attention kernels chosen by the backend JAX runs on.
+
+    On a TPU, decode, verify and paged attention run the compiled Pallas
+    kernels, and asking for the interpreter there is an error.  Elsewhere
+    they take the jnp path, or the Pallas interpreter when the config
+    asks for it (``pallas_interpret``, the CPU parity tests).  Entry
+    points call this once, where they build the config.
+    """
+    import jax
+
+    if jax.default_backend() == "tpu":
+        if cfg.pallas_interpret:
+            raise ValueError(
+                f"{cfg.name}: pallas_interpret=True on a TPU would run the "
+                f"Pallas interpreter on the chip; the compiled kernels "
+                f"are the TPU path")
+        return dataclasses.replace(cfg, use_pallas=True)
+    return dataclasses.replace(cfg, use_pallas=cfg.pallas_interpret)
+
+
 def reduce_config(cfg: ModelConfig) -> ModelConfig:
     """Shrink a config to smoke-test scale, preserving the family.
 

@@ -106,9 +106,11 @@ class ModelConfig:
     sublayer_remat: bool = False  # hybrid: checkpoint each of the 8
                                   # sublayers instead of the superblock
                                   # (jamba: ~4x lower temp memory)
-    use_pallas: bool = False      # flip on real TPU; CPU uses jnp refs
+    use_pallas: bool = False      # set from the backend by
+                                  # configs.for_platform (TPU: kernels)
     pallas_interpret: bool = False  # run the Pallas kernels in interpret
-                                  # mode (CPU correctness/parity tests)
+                                  # mode (CPU correctness/parity tests;
+                                  # refused on a TPU)
     quant: Optional[str] = None   # None | "int8" | "fp8" weight/act quant
     seq_shard_kv: bool = True     # sequence-shard KV cache for decode
     subquadratic: bool = False    # eligible for long_500k

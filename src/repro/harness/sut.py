@@ -333,11 +333,13 @@ class ServeEngineSUT(BaseSUT):
                  make_requests: Callable[[list[dict]], list],
                  system: SystemSpec = EDGE_SYSTEM, n_chips: int = 1,
                  sysdesc: Optional[SystemDescription] = None):
+        self.meter = SystemPowerModel(system, n_chips)
+        if sysdesc is None and system is not EDGE_SYSTEM:
+            sysdesc = _scaled_sysdesc(self.meter, "datacenter")
         super().__init__(name, sysdesc)
         self.engine = engine
         self.cfg = cfg
         self.make_requests = make_requests
-        self.meter = SystemPowerModel(system, n_chips)
 
     def issue(self, sample: dict) -> float:
         return self.issue_batch([sample])
@@ -364,7 +366,10 @@ class ContinuousBatchingSUT(BaseSUT):
     """Slot-based ``ContinuousBatchingEngine`` behind ``serve_queue``.
 
     ``make_request(i, sample, arrival_s) -> Request`` builds each
-    admission-queue entry.  Every domain trace is shaped by engine
+    admission-queue entry.  ``system`` is the modeled ``SystemSpec``
+    whose watts price the run: ``repro.hw.system_for_device`` of the
+    device it runs on (the edge default is the CPU's).  Every domain
+    trace is shaped by engine
     occupancy (idle floor + per-slot share of the busy draw over the
     completed requests' spans), so per-request energy attribution sees
     a realistic trace on every rail.
@@ -394,11 +399,13 @@ class ContinuousBatchingSUT(BaseSUT):
                  system: SystemSpec = EDGE_SYSTEM, n_chips: int = 1,
                  draft: Any = None,
                  sysdesc: Optional[SystemDescription] = None):
+        self.meter = SystemPowerModel(system, n_chips)
+        if sysdesc is None and system is not EDGE_SYSTEM:
+            sysdesc = _scaled_sysdesc(self.meter, "datacenter")
         super().__init__(name, sysdesc)
         self.engine = engine
         self.cfg = cfg
         self.make_request = make_request
-        self.meter = SystemPowerModel(system, n_chips)
         self.completed: list = []
         self.draft_cfg = draft
         if draft is not None:
@@ -474,6 +481,21 @@ def _system_peak_watts(meter: SystemPowerModel) -> float:
     return w / s.psu_efficiency
 
 
+def _scaled_sysdesc(meter: SystemPowerModel,
+                    scale: str) -> SystemDescription:
+    """The system description a submission at ``scale`` would state
+    for ``meter``'s chips: its power envelope and idle floor, and, for
+    a datacenter, node telemetry of documented accuracy (R4) in place
+    of a SPEC-approved analyzer."""
+    dc = scale == "datacenter"
+    return SystemDescription(
+        scale=scale, n_chips=meter.n_chips,
+        instrument="node-telemetry" if dc else "virtual-wt310",
+        telemetry_accuracy=0.01 if dc else None,
+        max_system_watts=_system_peak_watts(meter),
+        idle_system_watts=meter.system_watts(None))
+
+
 class ShardedSUT(ContinuousBatchingSUT):
     """Tensor-parallel ``ShardedContinuousBatchingEngine`` behind the
     SUT surface.
@@ -493,19 +515,10 @@ class ShardedSUT(ContinuousBatchingSUT):
                  draft: Any = None,
                  sysdesc: Optional[SystemDescription] = None):
         tp = engine.tp
-        meter = SystemPowerModel(system, tp)
         if sysdesc is None:
-            scale = scale or ("datacenter" if tp > 1 else "edge")
-            # datacenter submissions document node telemetry accuracy
-            # (R4) instead of a SPEC-approved analyzer
-            telemetry = 0.01 if scale == "datacenter" else None
-            sysdesc = SystemDescription(
-                scale=scale, n_chips=tp,
-                instrument=("node-telemetry" if scale == "datacenter"
-                            else "virtual-wt310"),
-                telemetry_accuracy=telemetry,
-                max_system_watts=_system_peak_watts(meter),
-                idle_system_watts=meter.system_watts(None))
+            scale = scale or ("datacenter" if tp > 1
+                              or system is not EDGE_SYSTEM else "edge")
+            sysdesc = _scaled_sysdesc(SystemPowerModel(system, tp), scale)
         super().__init__(engine, cfg, name=name,
                          make_request=make_request, system=system,
                          n_chips=tp, draft=draft, sysdesc=sysdesc)

@@ -39,15 +39,17 @@ class ChipSpec:
         )
 
 
-# TPU v5e-class target chip. Peak numbers are public (197 TFLOP/s bf16,
-# 819 GB/s HBM, 16 GiB); power/energy coefficients are estimates:
+# TPU v5e. Published peaks of one chip (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links x
+# 50 GB/s).  The power/energy coefficients are estimates, not published:
 #   e_flop  = (peak_watts - idle_watts) / peak_flops   ~ 0.74 pJ/FLOP
 #   e_hbm   ~ 3.9 pJ/bit HBM2e                         ~ 31  pJ/B
 #   e_ici   ~ 5 pJ/bit SerDes                          ~ 40  pJ/B
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
-    peak_flops_int8=394e12,
+    peak_flops_int8=393e12,
     hbm_bandwidth=819e9,
     hbm_capacity=16 * 2**30,
     ici_bandwidth=50e9,
@@ -189,3 +191,27 @@ SYSTEMS = {
     "datacenter-v5p": DATACENTER_V5P,
     "edge": EDGE_SYSTEM,
 }
+
+# The modeled system behind each accelerator JAX can report, keyed by
+# ``jax.Device.device_kind``.  Watts on a chip are priced from this
+# table; an accelerator missing from it is an error, never a default.
+SYSTEMS_BY_DEVICE_KIND = {
+    "TPU v5 lite": DATACENTER_V5E,
+}
+
+
+def system_for_device(device) -> SystemSpec:
+    """The modeled ``SystemSpec`` of the host around ``device``.
+
+    The CPU (tests, virtual devices) keeps the explicit edge system;
+    an accelerator must be listed in ``SYSTEMS_BY_DEVICE_KIND``.
+    """
+    if device.platform == "cpu":
+        return EDGE_SYSTEM
+    try:
+        return SYSTEMS_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no modeled system for {device.platform} device kind "
+            f"{device.device_kind!r}; add it to "
+            f"repro.hw.SYSTEMS_BY_DEVICE_KIND") from None

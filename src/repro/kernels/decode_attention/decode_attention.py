@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -154,7 +153,7 @@ def verify_attention_kernel(q, k, v, pos, *, block_k: int = 512,
             pltpu.VMEM((t * g, 1), jnp.float32),
             pltpu.VMEM((t * g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, q, k, v)
@@ -299,7 +298,7 @@ def paged_decode_attention_kernel(q, k, v, pages, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, g, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
@@ -342,7 +341,7 @@ def paged_verify_attention_kernel(q, k, v, pages, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, t, g, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
@@ -378,7 +377,7 @@ def decode_attention_kernel(q, k, v, pos, *, block_k: int = 512,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, q, k, v)

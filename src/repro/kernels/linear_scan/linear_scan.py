@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_final_ref,
             s_scr, *, chunk: int, n_chunks: int):
@@ -105,7 +103,7 @@ def linear_scan_kernel(r, k, v, logw, u, *, chunk: int = 64,
             jax.ShapeDtypeStruct((bh, dh, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u)

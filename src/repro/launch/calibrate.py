@@ -88,7 +88,7 @@ def _solve(rows: list[list[float]], costs: list[float],
 
 def calibrated_costs(cfg: ModelConfig, shape: ShapeConfig, mesh,
                      *, hp=None, verbose: bool = False) -> CalibratedCosts:
-    from repro.launch.roofline import collective_bytes, cost_analysis_dict
+    from repro.launch.roofline import collective_bytes
     from repro.launch.specs import build_cell
     import numpy as np
 
@@ -100,7 +100,7 @@ def calibrated_costs(cfg: ModelConfig, shape: ShapeConfig, mesh,
         vcfg = _variant(cfg, **ov)
         cell = build_cell(vcfg, shape, mesh, hp=hp)
         compiled = cell.lower().compile()
-        c = cost_analysis_dict(compiled)
+        c = compiled.cost_analysis()
         flops.append(float(c.get("flops", 0.0)))
         hbm.append(float(c.get("bytes accessed", 0.0)))
         coll = collective_bytes(compiled.as_text(), n_dev)

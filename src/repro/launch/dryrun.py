@@ -26,8 +26,7 @@ import traceback
 from repro.configs import (ASSIGNED_ARCHS, SHAPES, get_config,
                            shape_applicable)
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import (CellReport, analyze,
-                                   cost_analysis_dict, render_table)
+from repro.launch.roofline import CellReport, analyze, render_table
 from repro.launch.specs import build_cell
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
@@ -66,7 +65,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
     mem = compiled.memory_analysis()
     if verbose:
         print(f"  memory_analysis: {mem}")
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         print(f"  cost_analysis (raw, scan bodies counted once): "
               f"flops={cost.get('flops', 0):.4g} "
               f"bytes={cost.get('bytes accessed', 0):.4g}", flush=True)

@@ -7,16 +7,7 @@ must set XLA_FLAGS before the first jax initialization.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                           # AxisType landed after jax 0.4.x
-    from jax.sharding import AxisType
-
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:            # older jax: Auto is the only behavior
-    def _mesh_kwargs(n_axes: int) -> dict:
-        return {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,12 +15,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Multi-pod: 2x16x16 (pod, data, model) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     """Arbitrary mesh (scaling studies, tests, pipeline stages)."""
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> Mesh:

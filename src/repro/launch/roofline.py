@@ -159,18 +159,9 @@ def model_flops_for(cfg, shape) -> float:
     return 2.0 * n * shape.global_batch          # decode: one token each
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on new jax and a
-    one-element list of dicts on older releases; normalize to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze(cell, compiled, *, chip: ChipSpec = TPU_V5E,
             mesh_name: str = "") -> CellReport:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     txt = compiled.as_text()

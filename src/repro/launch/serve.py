@@ -54,16 +54,20 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import get_config, list_archs, reduce_config
+from repro.configs import (for_platform, get_config, list_archs,
+                           reduce_config)
 from repro.core.loadgen import qid_of
 from repro.harness import (ContinuousBatchingSUT, MultiStream, Offline,
                            PowerRun, ReplicatedSUT, ServeEngineSUT,
                            Server, ShardedSUT, SingleStream)
+from repro.hw import system_for_device
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_tp_mesh
 from repro.models import build_model
 from repro.models.param import init_params
 from repro.serving import (ContinuousBatchingEngine, Request, Scheduler,
                            ServeEngine, ShardedContinuousBatchingEngine,
-                           truncate_draft)
+                           tp_param_shardings, truncate_draft)
 
 
 def _make_request(key, cfg, i, arrival_s=0.0, new_tokens=8):
@@ -102,6 +106,7 @@ def _build_draft(args, cfg, model, params):
     dcfg = get_config(args.draft)
     if args.reduce:
         dcfg = reduce_config(dcfg)
+    dcfg = for_platform(dcfg)
     if dcfg.vocab_size != cfg.vocab_size:
         raise SystemExit(
             f"--draft {args.draft}: vocab {dcfg.vocab_size} != target "
@@ -112,7 +117,7 @@ def _build_draft(args, cfg, model, params):
     return dmodel, dparams, dcfg
 
 
-def _build_continuous_engine(args, model, params, spec_kw):
+def _build_continuous_engine(args, model, params, spec_kw, mesh):
     paged_kw = {}
     if args.kv_page_size:
         paged_kw = dict(kv_page_size=args.kv_page_size,
@@ -123,7 +128,7 @@ def _build_continuous_engine(args, model, params, spec_kw):
         paged_kw["scheduler"] = Scheduler(preemption=True)
     if args.tp > 1:
         return ShardedContinuousBatchingEngine(
-            model, params, tp=args.tp, max_len=args.max_len,
+            model, params, mesh=mesh, max_len=args.max_len,
             n_slots=args.slots, chunk_steps=args.chunk_steps,
             **paged_kw, **spec_kw)
     return ContinuousBatchingEngine(
@@ -131,7 +136,7 @@ def _build_continuous_engine(args, model, params, spec_kw):
         chunk_steps=args.chunk_steps, **paged_kw, **spec_kw)
 
 
-def _serve_continuous(args, cfg, model, params):
+def _serve_continuous(args, cfg, model, params, system, mesh):
     key = jax.random.PRNGKey(1)
 
     spec_kw, draft_cfg = {}, None
@@ -149,7 +154,8 @@ def _serve_continuous(args, cfg, model, params):
                              new_tokens=args.new_tokens)
 
     def one_sut(idx):
-        engine = _build_continuous_engine(args, model, params, spec_kw)
+        engine = _build_continuous_engine(args, model, params, spec_kw,
+                                          mesh)
         # warmup/compile: one prefill + one chunk outside the measurement
         engine.serve([_make_request(key, cfg, 10 ** 6,
                                     new_tokens=args.new_tokens)],
@@ -159,10 +165,11 @@ def _serve_continuous(args, cfg, model, params):
             f"-r{idx}" if args.replicas > 1 else "")
         if args.tp > 1:
             return ShardedSUT(engine, cfg, name=f"{name}-tp{args.tp}",
-                              make_request=make_request,
+                              make_request=make_request, system=system,
                               draft=draft_cfg), engine
         return ContinuousBatchingSUT(engine, cfg, name=name,
                                      make_request=make_request,
+                                     system=system,
                                      draft=draft_cfg), engine
 
     pairs = [one_sut(i) for i in range(args.replicas)]
@@ -184,7 +191,9 @@ def _serve_continuous(args, cfg, model, params):
           f"({m.total_tokens} tokens)")
     print(f"  {m.total_tokens / max(r.summary.energy_j, 1e-9):.3f} tok/J"
           + (f" across tp={args.tp}" if args.tp > 1 else "")
-          + (f" x {args.replicas} replicas" if args.replicas > 1 else ""))
+          + (f" x {args.replicas} replicas" if args.replicas > 1 else "")
+          + f" (modeled: measured time x {system.chip.name} system "
+            f"watts)")
     if args.speculative:
         acc = sum(e.spec_stats["accepted"] for e in engines) / max(
             1, sum(e.spec_stats["proposed"] for e in engines))
@@ -309,14 +318,20 @@ def main(argv=None):
                  "request's KV pages survive as cache entries until "
                  "resume)")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduce:
         cfg = reduce_config(cfg)
+    cfg = for_platform(cfg)
     model = build_model(cfg)
-    params = init_params(model.param_defs(), jax.random.PRNGKey(0))
+    system = system_for_device(jax.devices()[0])
+    # a tensor-parallel model is created straight into its shards
+    mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
+    params = init_params(model.param_defs(), jax.random.PRNGKey(0),
+                         tp_param_shardings(model, mesh) if mesh else None)
 
     if args.engine == "continuous":
-        _serve_continuous(args, cfg, model, params)
+        _serve_continuous(args, cfg, model, params, system, mesh)
         return
 
     batch_cap = max(args.batch, args.streams
@@ -336,7 +351,7 @@ def main(argv=None):
               "multi-stream": args.streams}.get(args.scenario, 1)
     engine.run_batch(make_requests([{"idx": j} for j in range(warm_n)]))
     sut = ServeEngineSUT(engine, cfg, name=f"{args.arch}-fixed",
-                         make_requests=make_requests)
+                         make_requests=make_requests, system=system)
     r = PowerRun(sut, _scenario_for(args), seed=0).run()
     print(r.render())
 

@@ -861,8 +861,6 @@ def _moe_shard_map(x, p, cfg, router_type, rules, eax):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.sharding import shard_map
-
     mo = cfg.moe
     mesh = rules.mesh
     eaxes = (eax,) if isinstance(eax, str) else tuple(eax)
@@ -919,11 +917,11 @@ def _moe_shard_map(x, p, cfg, router_type, rules, eax):
         aux = jax.lax.pmean(aux, eaxes)
         return y.reshape(bl, sl, dd), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_pspec, r_pspec, wg_pspec, wg_pspec, wd_pspec),
         out_specs=(x_pspec, P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if rules.table.get("batch") is not None:
         pass  # aux already pmean'd over expert axis; batch mean via loss

@@ -468,13 +468,6 @@ class LM:
                 "pages": jax.ShapeDtypeStruct((batch, pages_per_slot),
                                               jnp.int32)}
 
-    def init_paged_cache(self, batch: int, n_pages: int, page_size: int,
-                         pages_per_slot: int):
-        return jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            self.paged_cache_specs(batch, n_pages, page_size,
-                                   pages_per_slot))
-
     def paged_cache_pspecs(self, rules):
         """PartitionSpecs for ``paged_cache_specs``: pools partitioned
         by KV head (the TP split), table and positions replicated."""
@@ -497,8 +490,14 @@ class LM:
         return shard(x, "batch", "seq_sp", "embed")
 
     def _logits(self, params, x):
+        """f32 logits over the padded vocabulary; the pad columns are
+        masked, so neither argmax nor sampling can emit a token id past
+        ``vocab_size``."""
         x = L.rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
         logits = (x @ params["lm_head"]).astype(jnp.float32)
+        if self.vp > self.cfg.vocab_size:
+            pad = jnp.arange(self.vp) >= self.cfg.vocab_size
+            logits = jnp.where(pad, L.MASK_VALUE, logits)
         return shard(logits, "batch", "seq_sp", "vocab")
 
     # ------------------------------------------------------------------

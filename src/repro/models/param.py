@@ -44,12 +44,24 @@ def _init_one(d: ParamDef, key: jax.Array) -> jax.Array:
     return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dtype)
 
 
-def init_params(defs, key: jax.Array):
+def init_params(defs, key: jax.Array, shardings=None):
+    """Seeded parameters for a ``ParamDef`` tree.
+
+    ``shardings``: a matching tree of ``jax.sharding.Sharding``.  With
+    it, every leaf is created straight into its sharding by one jitted
+    program, so no full copy of a sharded model lands on one device.
+    The values do not depend on the sharding.
+    """
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_def)
-    out = []
-    for i, d in enumerate(leaves):
-        out.append(_init_one(d, jax.random.fold_in(key, i)))
-    return jax.tree.unflatten(treedef, out)
+
+    def build(key):
+        return jax.tree.unflatten(treedef, [
+            _init_one(d, jax.random.fold_in(key, i))
+            for i, d in enumerate(leaves)])
+
+    if shardings is None:
+        return build(key)
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 def abstract_params(defs):

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh, n_stages: int,
                      n_micro: int):
@@ -74,11 +72,11 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, n_stages: int,
         outputs = jax.lax.psum(outputs * mask, "stage")
         return outputs
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

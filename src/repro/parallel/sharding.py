@@ -23,35 +23,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import inspect
 import threading
 from typing import Optional, Sequence, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                           # jax >= 0.5 promotes shard_map to core
-    from jax import shard_map as _shard_map
-except ImportError:            # 0.4.x: the experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# core jax renamed check_rep -> check_vma (and 0.4.x knows only
-# check_rep); translate so call sites can stay on one spelling
-_SM_PARAMS = inspect.signature(_shard_map).parameters
-_REP_KW = ("check_rep" if "check_rep" in _SM_PARAMS else
-           "check_vma" if "check_vma" in _SM_PARAMS else None)
-
-
-def shard_map(fn, **kwargs):
-    """``jax.shard_map`` across jax versions (import path + the
-    ``check_rep``/``check_vma`` kwarg rename)."""
-    if "check_rep" in kwargs and _REP_KW != "check_rep":
-        kwargs = dict(kwargs)
-        val = kwargs.pop("check_rep")
-        if _REP_KW is not None:
-            kwargs[_REP_KW] = val
-    return _shard_map(fn, **kwargs)
-
 
 Axis = Union[None, str, tuple[str, ...]]
 

@@ -27,7 +27,7 @@ from repro.serving.kv_pages import (  # noqa: F401
 )
 from repro.serving.prefix_cache import PrefixCache  # noqa: F401
 from repro.serving.sharded import (  # noqa: F401
-    ShardedContinuousBatchingEngine,
+    ShardedContinuousBatchingEngine, tp_param_shardings,
 )
 from repro.serving.speculative import (  # noqa: F401
     damp_upper_layers, greedy_verify, speculative_sample, truncate_draft,

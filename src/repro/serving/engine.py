@@ -330,6 +330,7 @@ class ContinuousBatchingEngine:
                                       donate_argnums=(1,))
         self._install_slot = jax.jit(self._install_slot_impl,
                                      donate_argnums=(0,))
+        self._step_logits = jax.jit(self._step_logits_impl)
         self.reset()
 
     @staticmethod
@@ -357,9 +358,9 @@ class ContinuousBatchingEngine:
     def reset(self):
         """Fresh slot state: empty cache, zero positions, no budgets."""
         if self.paged:
-            cache = self.model.init_paged_cache(
+            cache = self._zeros(self.model.paged_cache_specs(
                 self.n_slots, self.n_pages, self.page_size,
-                self.pages_per_slot)
+                self.pages_per_slot))
             self.page_pool.reset()
             if self.prefix_cache is not None:
                 self.prefix_cache.reset()
@@ -374,8 +375,8 @@ class ContinuousBatchingEngine:
             self._slot_toks: list[tuple] = [()] * self.n_slots
             self._slot_base: list[int] = [0] * self.n_slots
         else:
-            cache = self.model.init_cache(self.n_slots, self.max_len,
-                                          per_slot_pos=True)
+            cache = self._zeros(self.model.cache_specs(
+                self.n_slots, self.max_len, per_slot_pos=True))
         self.state = {
             "cache": cache,
             "tok": jnp.zeros((self.n_slots,), jnp.int32),
@@ -386,6 +387,12 @@ class ContinuousBatchingEngine:
                 self.n_slots, self.max_len, per_slot_pos=True)
             if self.temperature > 0:
                 self.state["key"] = jax.random.PRNGKey(self.spec_seed)
+
+    def _zeros(self, specs):
+        """Zero-filled device arrays for the target cache's
+        ``ShapeDtypeStruct`` tree (the tensor-parallel subclass creates
+        them straight into their shardings)."""
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), specs)
 
     def _prefill_slot_impl(self, params, dparams, state, tokens, slot,
                            budget, pages=None):
@@ -585,6 +592,34 @@ class ContinuousBatchingEngine:
             (state["cache"], state["tok"], state["remaining"], buf0))
         return dict(state, cache=cache, tok=tok, remaining=remaining), buf
 
+    def _step_logits_impl(self, params, state):
+        """Logits (B, Vp) of every slot's next decode step; the state
+        is read, not advanced."""
+        with sharding_ctx(self.rules):
+            logits, _ = self.compute_model.decode_step(
+                params, state["cache"], state["tok"][:, None])
+        return logits[:, -1]
+
+    def first_step_logits(self, prompt) -> jax.Array:
+        """Prefill ``prompt`` into slot 0 of a fresh state and return
+        the logits (Vp,) of its first decode step.
+
+        A parity probe: two kernel paths or two layouts of the same
+        weights must give the same row.  The prompt is admitted exactly
+        as ``serve`` admits it (paged or contiguous, chunked or not).
+        """
+        self.reset()
+        r = Request(rid=0, prompt=prompt, max_new_tokens=2)
+        cx = _ServeCtx(slots=[None] * self.n_slots,
+                       slot_left=[0] * self.n_slots, filling={},
+                       ready=collections.deque(), parked=set(), done=[],
+                       now=time.monotonic, t0=time.monotonic())
+        if not self._admit_slot(r, 0, cx):
+            raise RuntimeError("probe prompt does not fit the page pool")
+        while cx.filling:
+            self._advance_prefill(0, cx)
+        return self._step_logits(self.params, self.state)[0]
+
     def _spec_chunk_impl(self, params, dparams, state):
         """Run ``chunk_steps`` draft-and-verify rounds fully on device.
 
@@ -635,9 +670,9 @@ class ContinuousBatchingEngine:
                 if sampled:
                     nxt = jax.random.categorical(
                         jax.random.fold_in(key_round, j),
-                        self._mask_pad(row) / self.temperature, axis=-1)
+                        row / self.temperature, axis=-1)
                     dlog = jax.lax.dynamic_update_slice(
-                        dlog, self._mask_pad(row)[:, None], (0, j, 0))
+                        dlog, row[:, None], (0, j, 0))
                 else:
                     nxt = jnp.argmax(row, axis=-1)
                 nxt = nxt.astype(jnp.int32)
@@ -665,7 +700,7 @@ class ContinuousBatchingEngine:
             if sampled:
                 acc, out_toks = speculative_sample(
                     jax.random.fold_in(key_round, k + 1),
-                    self._mask_pad(logits.astype(jnp.float32)), dlog,
+                    logits.astype(jnp.float32), dlog,
                     draft_toks, self.temperature)
             else:
                 acc, out_toks = greedy_verify(logits, draft_toks)
@@ -710,15 +745,6 @@ class ContinuousBatchingEngine:
         if sampled:
             st["key"] = key
         return st, out
-
-    def _mask_pad(self, logits):
-        """-inf the padded vocab tail before sampling (argmax paths stay
-        unmasked to match the plain engine exactly)."""
-        vocab = self.model.cfg.vocab_size
-        if logits.shape[-1] == vocab:
-            return logits
-        pad = jnp.arange(logits.shape[-1]) >= vocab
-        return jnp.where(pad, -1e30, logits)
 
     # -- paged admission (host side) -------------------------------------
     def _alloc_pages(self, n: int) -> list[int]:

@@ -28,10 +28,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.parallel.sharding import (make_tp_rules, param_pspecs,
-                                     shard_map, tp_ctx, tp_local_config)
+                                     tp_ctx, tp_local_config)
 from repro.serving.engine import ContinuousBatchingEngine
 
 
@@ -70,6 +70,25 @@ def replicate_kv_heads(model, params, tp: int):
     return type(model)(cfg2), params
 
 
+def _needs_kv_replication(cfg, tp: int) -> bool:
+    return tp > 1 and cfg.family == "dense" and cfg.n_kv_heads % tp != 0
+
+
+def tp_param_shardings(model, mesh: Mesh, axis: str = "model"):
+    """``NamedSharding`` tree of ``model``'s parameters in the
+    tensor-parallel engine's layout — pass it to ``init_params`` to
+    create a model that does not fit one device straight into its
+    shards.  (With fewer KV heads than shards the engine repeats the
+    K/V weights after creation; they are placed by the repeated
+    layout's rules here, and re-placed by the engine.)"""
+    cfg = model.cfg
+    tp = mesh.shape[axis]
+    if _needs_kv_replication(cfg, tp):
+        cfg = dataclasses.replace(cfg, n_kv_heads=tp, d_head=cfg.head_dim)
+    specs = param_pspecs(model.param_defs(), make_tp_rules(cfg, mesh, axis))
+    return jax.tree.map(lambda p: NamedSharding(mesh, p), specs)
+
+
 class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
     """``ContinuousBatchingEngine`` partitioned ``tp`` ways.
 
@@ -81,6 +100,11 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     ``tp=1`` degenerates to a 1-device mesh and is token-identical to
     the unsharded engine (the parity gate CI runs on virtual devices).
+
+    Parameters are placed in their shardings on entry (a no-op for
+    params created there by ``init_params(..., tp_param_shardings(...))``,
+    which is how a model larger than one device is built), and the KV
+    cache is created straight into its shards.
     """
 
     def __init__(self, model, params, *, tp: Optional[int] = None,
@@ -93,13 +117,14 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
         self.tp_axis = axis
         self.tp = mesh.shape[axis]
         cfg = model.cfg
-        if (self.tp > 1 and cfg.family == "dense"
-                and cfg.n_kv_heads % self.tp != 0):
+        if _needs_kv_replication(cfg, self.tp):
             model, params = replicate_kv_heads(model, params, self.tp)
             cfg = model.cfg
         local_cfg = tp_local_config(cfg, self.tp)
         rules = make_tp_rules(cfg, mesh, axis)
         self._param_specs = param_pspecs(model.param_defs(), rules)
+        params = jax.device_put(params, jax.tree.map(
+            lambda p: NamedSharding(mesh, p), self._param_specs))
         # paged mode (kw is parsed by super().__init__, but the cache
         # specs must exist first): the page pool partitions by KV head
         # exactly like the contiguous cache; page tables and positions
@@ -127,6 +152,21 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
             jax.tree.map(lambda _: P(), self.state),
             cache=self._cache_specs)
 
+    def reset(self):
+        super().reset()
+        # the rest of the state replicated over the mesh, as the jitted
+        # bodies return it: a fresh state then hits the same compiled
+        # programs as a used one
+        rep = NamedSharding(self.mesh, P())
+        self.state = {k: v if k == "cache" else jax.device_put(v, rep)
+                      for k, v in self.state.items()}
+
+    def _zeros(self, specs):
+        return jax.tree.map(
+            lambda s, p: jnp.zeros(s.shape, s.dtype,
+                                   device=NamedSharding(self.mesh, p)),
+            specs, self._cache_specs)
+
     def _shard_mapped(self, base_impl, in_specs, out_specs):
         """Wrap a base engine body in shard_map: params and cache enter
         partitioned (weights by head/FFN column, cache by KV head),
@@ -138,8 +178,8 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
             with tp_ctx(self.tp_axis):
                 return base_impl(*args)
 
-        return shard_map(local_fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(local_fn, mesh=self.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
     def _prefill_slot_impl(self, params, dparams, state, tokens, slot,
                            budget, pages=None):
@@ -200,3 +240,12 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
                       self._state_specs),
             out_specs=(self._state_specs, P()),
         )(params, dparams, state)
+
+    def _step_logits_impl(self, params, state):
+        # every shard holds the full (replicated) logits
+        base = super()._step_logits_impl
+        return self._shard_mapped(
+            base,
+            in_specs=(self._param_specs, self._state_specs),
+            out_specs=P(),
+        )(params, state)

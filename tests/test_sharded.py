@@ -85,7 +85,6 @@ def test_decode_kernel_shard_map_parity():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from repro.parallel.sharding import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels.decode_attention.ops import decode_attention
         from repro.launch.mesh import make_tp_mesh
@@ -100,13 +99,13 @@ def test_decode_kernel_shard_map_parity():
         full = decode_attention(q, kc, vc, pos, interpret=True)
 
         mesh = make_tp_mesh(4)
-        f = shard_map(
+        f = jax.shard_map(
             partial(decode_attention, interpret=True),
             mesh=mesh,
             in_specs=(P(None, None, "model", None),
                       P(None, None, "model", None),
                       P(None, None, "model", None), P()),
-            out_specs=P(None, None, "model", None), check_rep=False)
+            out_specs=P(None, None, "model", None), check_vma=False)
         sharded = jax.jit(f)(q, kc, vc, pos)
         np.testing.assert_allclose(np.asarray(sharded), np.asarray(full),
                                    rtol=1e-5, atol=1e-5)
@@ -138,6 +137,52 @@ def test_tp1_sharded_engine_degenerates_to_base():
                            honor_arrivals=False), key=lambda r: r.rid)
     for a, b in zip(ref, got):
         assert a.output == b.output, (a.rid, a.output, b.output)
+
+
+def test_params_and_kv_pool_created_in_their_shards():
+    """init_params(..., tp_param_shardings) creates every leaf straight
+    into its NamedSharding with the values it has replicated, the
+    engine keeps those arrays as they are, and its page
+    pool is created partitioned by KV head."""
+    out = run_py("""
+        import jax, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs import get_config, reduce_config
+        from repro.launch.mesh import make_tp_mesh
+        from repro.models import build_model
+        from repro.models.param import init_params
+        from repro.serving import (ShardedContinuousBatchingEngine,
+                                   tp_param_shardings)
+
+        import dataclasses
+        cfg = dataclasses.replace(reduce_config(get_config("yi-9b")),
+                                  n_kv_heads=4)
+        model = build_model(cfg)
+        mesh = make_tp_mesh(4)
+        key = jax.random.PRNGKey(3)
+        sh = tp_param_shardings(model, mesh)
+        params = init_params(model.param_defs(), key, sh)
+        # the same initializer with every leaf replicated
+        plain = init_params(model.param_defs(), key, jax.tree.map(
+            lambda _: NamedSharding(mesh, P()), sh))
+        for a, b, s in zip(jax.tree.leaves(params), jax.tree.leaves(plain),
+                           jax.tree.leaves(sh)):
+            assert a.sharding == s, (a.sharding, s)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        wq = params["blocks"]["attn"]["wq"]
+        assert wq.sharding.spec == P(None, None, "model"), wq.sharding
+        eng = ShardedContinuousBatchingEngine(model, params, mesh=mesh,
+                                              max_len=64, n_slots=2,
+                                              kv_page_size=16)
+        assert eng.params["blocks"]["attn"]["wq"] is wq
+        pool = eng.state["cache"]["layers"]["blocks"]["k"]
+        assert pool.sharding == NamedSharding(
+            mesh, P(None, None, None, "model", None)), pool.sharding
+        assert len(pool.addressable_shards) == 4
+        assert pool.addressable_shards[0].data.shape[3] == 1
+        print("PLACED-OK")
+    """)
+    assert "PLACED-OK" in out
 
 
 def test_replicate_kv_heads_exact():
