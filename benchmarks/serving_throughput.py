@@ -142,7 +142,7 @@ def metrics(smoke: bool = False) -> dict:
         }
     out["speedup"] = (out["continuous"]["tokens_per_s"]
                       / max(out["fixed"]["tokens_per_s"], 1e-12))
-    out["chunk_syncs"] = cont.host_syncs
+    out["chunk_syncs"] = cont.sched_stats["decode_chunks"]
     # gate the multi-channel sampling path itself on a representative
     # 4-channel rail stack (accelerator/dram/host + PSU-derived wall)
     from repro.core.compliance import SystemDescription

@@ -82,7 +82,7 @@ def main():
           f"SLO met: {r.outcome.slo_met}")
     print(f"  TTFT p99 {m.ttft_p(99) * 1e3:.1f} ms, "
           f"TPOT mean {m.tpot_mean * 1e3:.2f} ms, "
-          f"host syncs {cont.host_syncs}")
+          f"decode chunks {cont.sched_stats['decode_chunks']}")
     e = np.asarray(list((r.per_request_energy_j or {}).values()))
     print(f"energy: {r.summary.energy_j:.1f} J -> "
           f"{r.samples_per_joule:.4f} samples/J, "

@@ -183,11 +183,19 @@ def _serve_continuous(args, cfg, model, params, system, mesh):
     r = run.run()
 
     m = r.outcome.server
+    # queue wait: arrival to the admission that took the request into a
+    # slot; TTFT = queue wait + admission (prefill and first token)
+    waits = [q.admitted_s - q.arrival_s
+             for q in sut.completed_requests() or []
+             if q.admitted_s is not None]
+    qw = (f", queue wait p50/p90: {np.percentile(waits, 50) * 1e3:.1f}/"
+          f"{np.percentile(waits, 90) * 1e3:.1f} ms" if waits else "")
     print(r.render())
     print(f"  TTFT p50/p99: {m.ttft_p(50) * 1e3:.1f}/"
-          f"{m.ttft_p(99) * 1e3:.1f} ms, "
+          f"{m.ttft_p(99) * 1e3:.1f} ms{qw}, "
           f"TPOT mean: {m.tpot_mean * 1e3:.2f} ms, "
-          f"host syncs: {sum(e.host_syncs for e in engines)} "
+          f"decode chunks: "
+          f"{sum(e.sched_stats['decode_chunks'] for e in engines)} "
           f"({m.total_tokens} tokens)")
     print(f"  {m.total_tokens / max(r.summary.energy_j, 1e-9):.3f} tok/J"
           + (f" across tp={args.tp}" if args.tp > 1 else "")

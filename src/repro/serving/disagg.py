@@ -213,7 +213,6 @@ class DisaggregatedEngine:
         eng.reset()
         eng.prefix_stats = eng._zero_prefix_stats()
         eng.sched_stats = eng._zero_sched_stats()
-        eng.host_syncs = 0
         for w in self.workers:
             w.prefill_tokens = 0
         t0 = now()
@@ -272,7 +271,6 @@ class DisaggregatedEngine:
                 continue
             eng.state, buf = eng._decode_chunk(eng.params, eng.state)
             buf_np = np.asarray(jax.device_get(buf))
-            eng.host_syncs += 1
             eng.sched_stats["decode_chunks"] += 1
             t_chunk = now() - t0
             for b in range(eng.n_slots):

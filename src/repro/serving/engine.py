@@ -31,6 +31,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.parallel.sharding import ShardingRules, sharding_ctx, tp_ctx
 from repro.serving.kv_pages import GARBAGE_PAGE, PagePool, PoolExhausted
@@ -75,6 +76,9 @@ class Request:
     deadline_s: Optional[float] = None   # completion deadline, serve
                                          # clock (None = best effort)
     # filled by the engine:
+    admitted_s: Optional[float] = None   # first taken from the ready
+                                         # queue into a slot (serve clock;
+                                         # kept across a park and resume)
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
     output: Optional[list] = None
@@ -213,9 +217,9 @@ class ContinuousBatchingEngine:
         done = eng.serve(requests)          # honors Request.arrival_s
 
     Per decode chunk the host performs exactly one device->host sync
-    (``host_syncs`` counts them); tokens, greedy sampling, per-slot
-    position advance and done flags all stay on device inside a
-    ``lax.fori_loop``.
+    (``sched_stats["decode_chunks"]`` counts the chunks); tokens, greedy
+    sampling, per-slot position advance and done flags all stay on
+    device inside a ``lax.fori_loop``.
 
     Speculative decoding (``spec_k > 0`` with a ``draft_model``): each
     chunk runs ``chunk_steps`` draft-and-verify rounds instead of
@@ -269,7 +273,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"draft vocab {draft_model.cfg.vocab_size} != target "
                 f"vocab {model.cfg.vocab_size}")
-        self.host_syncs = 0            # decode-chunk device->host syncs
         # speculative accounting (host-accumulated, reset per serve):
         # rounds/proposed/accepted over live slots, prefill token counts
         self.spec_stats = self._zero_spec_stats()
@@ -963,7 +966,8 @@ class ContinuousBatchingEngine:
         """Stamp and route a just-admitted request: emit its first (or
         continuation) token, account speculative prefill work, and
         either retire it or hand the slot to the decode loop."""
-        first = int(tok0)              # blocks -> true TTFT
+        with TraceAnnotation("engine.first_token", rid=r.rid):
+            first = int(tok0)          # blocks -> true TTFT
         t_now = cx.now() - cx.t0
         if resume:
             r.output.append(first)
@@ -1011,6 +1015,19 @@ class ContinuousBatchingEngine:
         decoding slots keep emitting while long prompts fill (chunked
         prefill; token-identical to monolithic).  ``sched_stats``
         counts preemptions, resumes, and chunk interleaving per serve.
+
+        ``admitted_s`` is stamped, on the same clock, when a request
+        leaves the ready queue for an admission that succeeds (a
+        deferral does not stamp; a resume keeps the first stamp; under
+        chunked prefill the same iteration runs its first chunk), so
+        ``admitted_s - arrival_s`` is its queue wait.  Every iteration
+        and each host phase in it is a ``jax.profiler.TraceAnnotation``
+        span (``engine.step`` around ``engine.admit`` >
+        ``engine.first_token``, ``engine.prefill_chunk``,
+        ``engine.decode`` > ``engine.decode_sync``, ``engine.stitch``
+        and ``engine.wait_arrival``): a profiler that is running puts
+        them on the host plane of its trace, on the device's clock;
+        without one each costs about a microsecond.
         """
         counts = collections.Counter(r.rid for r in requests)
         dup = sorted(r for r, c in counts.items() if c > 1)
@@ -1023,7 +1040,6 @@ class ContinuousBatchingEngine:
         self.spec_stats = self._zero_spec_stats()
         self.prefix_stats = self._zero_prefix_stats()
         self.sched_stats = self._zero_sched_stats()
-        self.host_syncs = 0            # per-serve, like spec_stats
         pending = collections.deque(
             sorted(requests, key=lambda r: (r.arrival_s, r.rid)))
         cx = _ServeCtx(slots=[None] * self.n_slots,
@@ -1032,62 +1048,81 @@ class ContinuousBatchingEngine:
                        done=[], now=now, t0=now())
         while (pending or cx.ready or cx.filling
                or any(s is not None for s in cx.slots)):
-            t = now() - cx.t0
-            while pending and (not honor_arrivals
-                               or pending[0].arrival_s <= t):
-                cx.ready.append(pending.popleft())
-            if self.scheduler is not None and len(cx.ready) > 1:
-                ordered = self.scheduler.order(cx.ready, t)
-                cx.ready.clear()
-                cx.ready.extend(ordered)
-            # admit arrived requests into free slots (prefill-into-slot
-            # or, chunked, start the prompt's chunk cursor)
-            for b in range(self.n_slots):
-                if (cx.slots[b] is not None or b in cx.filling
-                        or not cx.ready):
-                    continue
-                r = cx.ready.popleft()
-                if not self._admit_slot(r, b, cx):
-                    # defer: a retiring slot will free its pages
-                    cx.ready.appendleft(r)
-                    if (not cx.filling and not any(
-                            s is not None for s in cx.slots)):
-                        raise RuntimeError(
-                            f"request {r.rid} needs more KV pages "
-                            f"than eviction can ever free (pool of "
-                            f"{self.page_pool.n_pages - 1} usable "
-                            f"pages)")
-                    break
-            # chunked prefill: one chunk per filling slot per iteration
-            for b in list(cx.filling):
-                self._advance_prefill(b, cx)
-            if not any(s is not None for s in cx.slots):
-                if cx.filling:
-                    continue           # keep chunking the prompt(s)
-                if not cx.ready:
-                    if not pending:
+            with TraceAnnotation("engine.step"):
+                t = now() - cx.t0
+                while pending and (not honor_arrivals
+                                   or pending[0].arrival_s <= t):
+                    cx.ready.append(pending.popleft())
+                if self.scheduler is not None and len(cx.ready) > 1:
+                    ordered = self.scheduler.order(cx.ready, t)
+                    cx.ready.clear()
+                    cx.ready.extend(ordered)
+                # admit arrived requests into free slots (prefill-into-
+                # slot or, chunked, start the prompt's chunk cursor)
+                for b in range(self.n_slots):
+                    if (cx.slots[b] is not None or b in cx.filling
+                            or not cx.ready):
+                        continue
+                    r = cx.ready.popleft()
+                    t_admit = now() - cx.t0
+                    with TraceAnnotation("engine.admit", rid=r.rid):
+                        admitted = self._admit_slot(r, b, cx)
+                    if not admitted:
+                        # defer: a retiring slot will free its pages
+                        cx.ready.appendleft(r)
+                        if (not cx.filling and not any(
+                                s is not None for s in cx.slots)):
+                            raise RuntimeError(
+                                f"request {r.rid} needs more KV pages "
+                                f"than eviction can ever free (pool of "
+                                f"{self.page_pool.n_pages - 1} usable "
+                                f"pages)")
                         break
-                    if honor_arrivals:
-                        dt = pending[0].arrival_s - (now() - cx.t0)
-                        if dt > 0:
-                            sleep(dt)
-                continue
-            # one fused multi-token chunk; a single host sync after it
+                    if r.admitted_s is None:   # a resume keeps the first
+                        r.admitted_s = t_admit
+                # chunked prefill: one chunk per filling slot per iteration
+                for b in list(cx.filling):
+                    with TraceAnnotation("engine.prefill_chunk",
+                                         rid=cx.filling[b].r.rid):
+                        self._advance_prefill(b, cx)
+                if not any(s is not None for s in cx.slots):
+                    if cx.filling:
+                        continue           # keep chunking the prompt(s)
+                    if not cx.ready:
+                        if not pending:
+                            break
+                        if honor_arrivals:
+                            dt = pending[0].arrival_s - (now() - cx.t0)
+                            if dt > 0:
+                                with TraceAnnotation("engine.wait_arrival"):
+                                    sleep(dt)
+                    continue
+                self._decode_and_stitch(cx)
+        return cx.done
+
+    def _decode_and_stitch(self, cx: "_ServeCtx") -> None:
+        """One fused multi-token decode chunk for every live slot with a
+        single host sync after it, then the per-slot stitch: each
+        slot's tokens join its request, and a request whose budget is
+        spent retires and frees its slot."""
+        with TraceAnnotation("engine.decode"):
             if self.speculative:
                 self.state, out = self._spec_chunk(
                     self.params, self.draft_params, self.state)
-                out = jax.device_get(out)
+                with TraceAnnotation("engine.decode_sync"):
+                    out = jax.device_get(out)
                 buf_np = np.asarray(out["buf"])      # (B, rounds, k+1)
                 n_emit = np.asarray(out["n_emit"])   # (B, rounds)
             else:
                 self.state, buf = self._decode_chunk(self.params,
                                                      self.state)
-                buf_np = np.asarray(jax.device_get(buf))
-            self.host_syncs += 1
-            self.sched_stats["decode_chunks"] += 1
-            if cx.filling:             # decode emitted while a prompt
-                self.sched_stats["interleaved_chunks"] += 1  # filled
-            t_chunk = now() - cx.t0
+                with TraceAnnotation("engine.decode_sync"):
+                    buf_np = np.asarray(jax.device_get(buf))
+        self.sched_stats["decode_chunks"] += 1
+        if cx.filling:                 # decode emitted while a prompt
+            self.sched_stats["interleaved_chunks"] += 1  # filled
+        t_chunk = cx.now() - cx.t0
+        with TraceAnnotation("engine.stitch"):
             for b in range(self.n_slots):
                 r = cx.slots[b]
                 if r is None:
@@ -1115,7 +1150,6 @@ class ContinuousBatchingEngine:
                     cx.done.append(r)
                     cx.slots[b] = None
                     self._release_slot(b)
-        return cx.done
 
     def tokens_per_request(self, requests: list[Request]) -> int:
         """Total emitted tokens (the efficiency denominators' work)."""

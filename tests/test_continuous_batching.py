@@ -61,7 +61,7 @@ def test_continuous_matches_fixed_batch_greedy():
     # zero per-token host syncs inside a chunk: the only decode-loop
     # syncs are the once-per-chunk buffer fetches
     decode_tokens = sum(max(0, b - 1) for b in budgets)
-    assert eng.host_syncs < decode_tokens
+    assert eng.sched_stats["decode_chunks"] < decode_tokens
     for r in done:
         assert r.first_token_s is not None and r.done_s is not None
 
