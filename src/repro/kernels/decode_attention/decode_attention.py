@@ -159,24 +159,26 @@ def verify_attention_kernel(q, k, v, pos, *, block_k: int = 512,
     )(pos_arr, q, k, v)
 
 
-def _paged_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
-                  l_scr, acc_scr, *, scale: float, page_size: int,
-                  n_pages: int, kvh: int):
+def _paged_kernel(pages_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref,
+                  o_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                  page_size: int, kvh: int):
     """Page-table-indirect decode attention.
 
     Same flash-decoding recurrence as ``_kernel``, but the KV block for
     grid step ``(ib, ik)`` is *physical page* ``pages[ib // kvh, ik]``
-    of the shared pool — the scalar-prefetched table drives the block
-    index maps, so the DMA engine streams pages in logical order while
-    they sit anywhere in the pool.  Because K/V values at positions
-    ``<= pos`` are identical to the contiguous layout and every other
-    position is masked to ``NEG_INF`` before the softmax, the output is
-    bit-identical to ``_kernel`` for any page permutation (garbage-page
-    reads included: those rows are always masked).
+    of head ``ib % kvh`` in layer ``layer`` of the stacked pool — the
+    scalar-prefetched table and layer index drive the block index maps,
+    so the DMA engine streams pages in logical order while they sit
+    anywhere in the pool, and no layer of the pool is ever sliced out.
+    Because K/V values at positions ``<= pos`` are identical to the
+    contiguous layout and every other position is masked to ``NEG_INF``
+    before the softmax, the output is bit-identical to ``_kernel`` for
+    any page permutation (garbage-page reads included: those rows are
+    always masked).
     """
     ib = pl.program_id(0)
     ik = pl.program_id(1)
-    del pages_ref, n_pages     # consumed by the index maps / grid
+    del pages_ref, layer_ref   # consumed by the index maps
 
     @pl.when(ik == 0)
     def _init():
@@ -189,8 +191,8 @@ def _paged_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
     @pl.when(ik * page_size <= pos)
     def _step():
         q = q_ref[0].astype(jnp.float32)              # (G, d)
-        k = k_ref[0, 0].astype(jnp.float32)           # (ps, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)        # (ps, d)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (G, ps)
@@ -213,9 +215,9 @@ def _paged_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
                     jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_verify_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float,
-                         page_size: int, n_pages: int, kvh: int, t: int,
+def _paged_verify_kernel(pages_ref, pos_ref, layer_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                         scale: float, page_size: int, kvh: int, t: int,
                          g: int):
     """Multi-token verify through the page table (``_verify_kernel``
     with paged KV blocks): window rows mask their own causal diagonal,
@@ -224,7 +226,7 @@ def _paged_verify_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     pages."""
     ib = pl.program_id(0)
     ik = pl.program_id(1)
-    del pages_ref, n_pages
+    del pages_ref, layer_ref
 
     @pl.when(ik == 0)
     def _init():
@@ -237,8 +239,8 @@ def _paged_verify_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ik * page_size <= pos + t - 1)
     def _step():
         q = q_ref[0].astype(jnp.float32).reshape(t * g, -1)   # (t*g, d)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (ps, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)                # (ps, d)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (t*g, ps)
@@ -262,32 +264,41 @@ def _paged_verify_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = o.reshape(t, g, o.shape[-1]).astype(o_ref.dtype)
 
 
-def paged_decode_attention_kernel(q, k, v, pages, pos, *,
+def _paged_kv_spec(kvh: int, page_size: int, d: int) -> pl.BlockSpec:
+    """One page of one KV head of one layer of the stacked pool
+    (L, KVH, P, page_size, D): grid row ``ib`` is slot ``ib // kvh``,
+    head ``ib % kvh``; its ``ik``-th block is the table's page."""
+    return pl.BlockSpec(
+        (1, 1, 1, page_size, d),
+        lambda ib, ik, pages, pos, layer: (
+            layer[0], ib % kvh, pages[ib // kvh, ik], 0, 0))
+
+
+def paged_decode_attention_kernel(q, k, v, pages, pos, layer, *,
                                   interpret: bool = False) -> jax.Array:
     """q: (BH, G, D) slot-major (row = slot * KVH + head); k, v:
-    (KVH, P, page_size, D) pool; pages: (B, NB) int32 page table;
-    pos: (B,) int32 per-slot depth.  Returns (BH, G, D)."""
+    (L, KVH, P, page_size, D) stacked pool; pages: (B, NB) int32 page
+    table; pos: (B,) int32 per-slot depth; layer: () int32, the layer
+    of the pool to read.  Returns (BH, G, D)."""
     bh, g, d = q.shape
-    kvh, _, page_size, _ = k.shape
+    _, kvh, _, page_size, _ = k.shape
     b, nb = pages.shape
     assert bh == b * kvh, (bh, b, kvh)
     scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_paged_kernel, scale=scale,
-                               page_size=page_size, n_pages=k.shape[1],
-                               kvh=kvh)
-    kv_spec = pl.BlockSpec(
-        (1, 1, page_size, d),
-        lambda ib, ik, pages, pos: (ib % kvh, pages[ib // kvh, ik], 0, 0))
+                               page_size=page_size, kvh=kvh)
+    kv_spec = _paged_kv_spec(kvh, page_size, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(bh, nb),
         in_specs=[
-            pl.BlockSpec((1, g, d), lambda ib, ik, pages, pos: (ib, 0, 0)),
+            pl.BlockSpec((1, g, d),
+                         lambda ib, ik, pages, pos, layer: (ib, 0, 0)),
             kv_spec,
             kv_spec,
         ],
         out_specs=pl.BlockSpec((1, g, d),
-                               lambda ib, ik, pages, pos: (ib, 0, 0)),
+                               lambda ib, ik, pages, pos, layer: (ib, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -301,36 +312,34 @@ def paged_decode_attention_kernel(q, k, v, pages, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
+    )(pages.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k, v)
 
 
-def paged_verify_attention_kernel(q, k, v, pages, pos, *,
+def paged_verify_attention_kernel(q, k, v, pages, pos, layer, *,
                                   interpret: bool = False) -> jax.Array:
-    """q: (BH, T, G, D) slot-major; k, v: (KVH, P, page_size, D) pool;
-    pages: (B, NB) int32; pos: (B,) int32 per-slot window start.
-    Returns (BH, T, G, D)."""
+    """q: (BH, T, G, D) slot-major; k, v: (L, KVH, P, page_size, D)
+    stacked pool; pages: (B, NB) int32; pos: (B,) int32 per-slot window
+    start; layer: () int32.  Returns (BH, T, G, D)."""
     bh, t, g, d = q.shape
-    kvh, _, page_size, _ = k.shape
+    _, kvh, _, page_size, _ = k.shape
     b, nb = pages.shape
     assert bh == b * kvh, (bh, b, kvh)
     scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_paged_verify_kernel, scale=scale,
-                               page_size=page_size, n_pages=k.shape[1],
-                               kvh=kvh, t=t, g=g)
-    kv_spec = pl.BlockSpec(
-        (1, 1, page_size, d),
-        lambda ib, ik, pages, pos: (ib % kvh, pages[ib // kvh, ik], 0, 0))
+                               page_size=page_size, kvh=kvh, t=t, g=g)
+    kv_spec = _paged_kv_spec(kvh, page_size, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(bh, nb),
         in_specs=[
             pl.BlockSpec((1, t, g, d),
-                         lambda ib, ik, pages, pos: (ib, 0, 0, 0)),
+                         lambda ib, ik, pages, pos, layer: (ib, 0, 0, 0)),
             kv_spec,
             kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, t, g, d),
-                               lambda ib, ik, pages, pos: (ib, 0, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, t, g, d), lambda ib, ik, pages, pos, layer: (ib, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((t * g, 1), jnp.float32),
             pltpu.VMEM((t * g, 1), jnp.float32),
@@ -344,7 +353,8 @@ def paged_verify_attention_kernel(q, k, v, pages, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
+    )(pages.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k, v)
 
 
 def decode_attention_kernel(q, k, v, pos, *, block_k: int = 512,

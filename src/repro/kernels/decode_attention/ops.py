@@ -98,49 +98,47 @@ def verify_attention(q, k_cache, v_cache, pos, *,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pool, v_pool, pages, pos, *,
+def paged_decode_attention(q, k_pool, v_pool, pages, pos, layer, *,
                            interpret: bool = False):
     """Page-table-indirect decode attention in model layout.
 
-    q: (B, 1, H, D); pools: (P, page_size, KVH, D) shared physical
-    pages; pages: (B, NB) int32 per-slot page table; pos: () or (B,)
-    int32.  Returns (B, 1, H, D).  The pool is transposed to KV-head-
-    major so each grid row streams its own head's pages, and the table
-    is scalar-prefetched to drive the KV block index maps.  Values are
-    bit-identical to ``decode_attention`` on the equivalent contiguous
-    cache for any page permutation.
+    q: (B, 1, H, D); pools: (L, KVH, P, page_size, D), the stacked
+    per-layer pools of shared physical pages, KV-head-major as the
+    kernel streams them; pages: (B, NB) int32 per-slot page table; pos:
+    () or (B,) int32; layer: () int32, the layer to attend over.
+    Returns (B, 1, H, D).  The pools are read where they lie: the table
+    and the layer are scalar-prefetched to drive the KV block index
+    maps, so neither a layer slice nor a transpose of the pool is ever
+    made.  Values are bit-identical to ``decode_attention`` on the
+    equivalent contiguous cache for any page permutation.
     """
     b, _, h, d = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[1]
     g = h // kvh
     qr = q[:, 0].reshape(b, kvh, g, d).reshape(b * kvh, g, d)
-    kr = k_pool.transpose(2, 0, 1, 3)          # (KVH, P, ps, D)
-    vr = v_pool.transpose(2, 0, 1, 3)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    o = paged_decode_attention_kernel(qr, kr, vr, pages, pos,
-                                      interpret=interpret)
+    o = paged_decode_attention_kernel(qr, k_pool, v_pool, pages, pos,
+                                      layer, interpret=interpret)
     return o.reshape(b, h, d)[:, None]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_verify_attention(q, k_pool, v_pool, pages, pos, *,
+def paged_verify_attention(q, k_pool, v_pool, pages, pos, layer, *,
                            interpret: bool = False):
     """Multi-token verify through the page table (speculative windows
     and prefix-cache suffix prefill).
 
-    q: (B, T, H, D); pools: (P, page_size, KVH, D); pages: (B, NB)
-    int32; pos: () or (B,) int32 per-slot window start.  Returns
-    (B, T, H, D)."""
+    q: (B, T, H, D); pools: (L, KVH, P, page_size, D); pages: (B, NB)
+    int32; pos: () or (B,) int32 per-slot window start; layer: ()
+    int32.  Returns (B, T, H, D)."""
     b, t, h, d = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[1]
     g = h // kvh
     qr = q.reshape(b, t, kvh, g, d).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(b * kvh, t, g, d)
-    kr = k_pool.transpose(2, 0, 1, 3)
-    vr = v_pool.transpose(2, 0, 1, 3)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    o = paged_verify_attention_kernel(qr, kr, vr, pages, pos,
-                                      interpret=interpret)
+    o = paged_verify_attention_kernel(qr, k_pool, v_pool, pages, pos,
+                                      layer, interpret=interpret)
     return o.reshape(b, kvh, t, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, t, h, d)
 
@@ -230,28 +228,36 @@ def _paged_table(case):
     return lambda slot, ik: perm[slot * nb + ik]
 
 
+def _paged_kv_operands(case, dt):
+    """The stacked K and V pools (L, KVH, P, page_size, D) as the paged
+    kernels block them: one page of one head of layer ``layer``."""
+    kvh, d = case["kvh"], case["d"]
+    ps, n_pages = case["page_size"], case["n_pages"]
+    n_layers, layer = case["n_layers"], case["layer"]
+    table = _paged_table(case)
+    kv_map = lambda bb, ik: (layer, bb % kvh, table(bb // kvh, ik), 0, 0)
+    return tuple(
+        OperandSpec(name, (n_layers, kvh, n_pages, ps, d), dt,
+                    block=(1, 1, 1, ps, d), index_map=kv_map)
+        for name in ("k", "v"))
+
+
 def _paged_decode_contract(case):
     b, nb = case["b"], case["nb"]
     h, kvh, d = case["h"], case["kvh"], case["d"]
-    ps, n_pages = case["page_size"], case["n_pages"]
     g = h // kvh
     bh = b * kvh
     dt = case.get("dtype", "bfloat16")
-    table = _paged_table(case)
-    kv_map = lambda bb, ik: (bb % kvh, table(bb // kvh, ik), 0, 0)
     return KernelInstance(
         grid=(bh, nb),
         semantics=("parallel", "arbitrary"),
         inputs=(
             OperandSpec("pages", (b, nb), "int32", memory_space="smem"),
             OperandSpec("pos", (b,), "int32", memory_space="smem"),
+            OperandSpec("layer", (1,), "int32", memory_space="smem"),
             OperandSpec("q", (bh, g, d), dt, block=(1, g, d),
                         index_map=lambda bb, ik: (bb, 0, 0)),
-            OperandSpec("k", (kvh, n_pages, ps, d), dt,
-                        block=(1, 1, ps, d), index_map=kv_map),
-            OperandSpec("v", (kvh, n_pages, ps, d), dt,
-                        block=(1, 1, ps, d), index_map=kv_map),
-        ),
+        ) + _paged_kv_operands(case, dt),
         outputs=(
             OperandSpec("o", (bh, g, d), dt, block=(1, g, d),
                         index_map=lambda bb, ik: (bb, 0, 0)),
@@ -267,25 +273,19 @@ def _paged_decode_contract(case):
 def _paged_verify_contract(case):
     b, t, nb = case["b"], case["t"], case["nb"]
     h, kvh, d = case["h"], case["kvh"], case["d"]
-    ps, n_pages = case["page_size"], case["n_pages"]
     g = h // kvh
     bh = b * kvh
     dt = case.get("dtype", "bfloat16")
-    table = _paged_table(case)
-    kv_map = lambda bb, ik: (bb % kvh, table(bb // kvh, ik), 0, 0)
     return KernelInstance(
         grid=(bh, nb),
         semantics=("parallel", "arbitrary"),
         inputs=(
             OperandSpec("pages", (b, nb), "int32", memory_space="smem"),
             OperandSpec("pos", (b,), "int32", memory_space="smem"),
+            OperandSpec("layer", (1,), "int32", memory_space="smem"),
             OperandSpec("q", (bh, t, g, d), dt, block=(1, t, g, d),
                         index_map=lambda bb, ik: (bb, 0, 0, 0)),
-            OperandSpec("k", (kvh, n_pages, ps, d), dt,
-                        block=(1, 1, ps, d), index_map=kv_map),
-            OperandSpec("v", (kvh, n_pages, ps, d), dt,
-                        block=(1, 1, ps, d), index_map=kv_map),
-        ),
+        ) + _paged_kv_operands(case, dt),
         outputs=(
             OperandSpec("o", (bh, t, g, d), dt, block=(1, t, g, d),
                         index_map=lambda bb, ik: (bb, 0, 0, 0)),
@@ -330,12 +330,13 @@ CONTRACTS = (
         build=_paged_decode_contract,
         cases=(
             # serving shape: 8 slots x 32 pages of 128 tokens (max_len
-            # 4096), pool sized one-page-per-slot-worth + garbage page
+            # 4096), pool sized one-page-per-slot-worth + garbage page,
+            # read at the last of 28 stacked layers
             {"b": 8, "nb": 32, "page_size": 128, "n_pages": 257,
-             "h": 16, "kvh": 4, "d": 128},
+             "n_layers": 28, "layer": 27, "h": 16, "kvh": 4, "d": 128},
             # small-page CI shape (matches the engine parity tests)
             {"b": 3, "nb": 8, "page_size": 128, "n_pages": 25,
-             "h": 8, "kvh": 2, "d": 64},
+             "n_layers": 2, "layer": 0, "h": 8, "kvh": 2, "d": 64},
         ),
         dtype_groups=(("q", "k", "v", "o"),),
     ),
@@ -345,10 +346,10 @@ CONTRACTS = (
         cases=(
             # speculative verify window of 4 draft tokens, paged pool
             {"b": 8, "t": 4, "nb": 32, "page_size": 128, "n_pages": 257,
-             "h": 16, "kvh": 4, "d": 128},
+             "n_layers": 28, "layer": 13, "h": 16, "kvh": 4, "d": 128},
             # prefix-cache suffix prefill: longer window, fewer slots
             {"b": 2, "t": 8, "nb": 8, "page_size": 128, "n_pages": 17,
-             "h": 8, "kvh": 2, "d": 64},
+             "n_layers": 2, "layer": 1, "h": 8, "kvh": 2, "d": 64},
         ),
         dtype_groups=(("q", "k", "v", "o"),),
     ),

@@ -436,86 +436,108 @@ def gqa_verify(x, p, cfg, cache, pos):
 # ----------------------------------------------------------------------
 # Paged KV: gather/scatter through a per-slot page table
 # ----------------------------------------------------------------------
-def paged_view(pool: jax.Array, pages: jax.Array) -> jax.Array:
-    """Gather a slot-contiguous view of a paged KV pool.
+def paged_view(pool: jax.Array, pages: jax.Array, layer) -> jax.Array:
+    """Gather a slot-contiguous view of one layer of a paged KV pool.
 
-    pool: (P, page_size, KVH, D) physical pages; pages: (B, NB) int32
-    page table.  Returns (B, NB * page_size, KVH, D) — logical row
-    ``j`` of slot ``b`` is ``pool[pages[b, j // ps], j % ps]``, so for
-    any permutation of physical pages the view is bit-identical to the
+    pool: (L, KVH, P, page_size, D), the stacked per-layer pools of
+    physical pages, KV-head-major; pages: (B, NB) int32 page table;
+    layer: the layer to read.  Returns (B, NB * page_size, KVH, D) —
+    logical row ``j`` of slot ``b`` and head ``h`` is
+    ``pool[layer, h, pages[b, j // ps], j % ps]``, so for any
+    permutation of physical pages the view is bit-identical to the
     contiguous cache layout (rows beyond ``pos`` are stale and masked
     by the position-aware attention, exactly like the zero tail of the
     contiguous cache).
     """
     b, nb = pages.shape
-    g = jnp.take(pool, pages, axis=0)          # (B, NB, ps, KVH, D)
-    return g.reshape(b, nb * pool.shape[1], *pool.shape[2:])
+    _, kvh, _, ps, d = pool.shape
+    g = pool[layer, :, pages]                  # (B, NB, KVH, ps, D)
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, nb * ps, kvh, d)
 
 
 def paged_insert_window(pool: jax.Array, new: jax.Array, pages: jax.Array,
-                        pos) -> jax.Array:
-    """Scatter ``new`` (B, T, KVH, D) into the pool at logical rows
+                        pos, layer) -> jax.Array:
+    """Scatter ``new`` (B, T, KVH, D) into layer ``layer`` of the
+    stacked pool (L, KVH, P, page_size, D) at logical rows
     ``[pos, pos + T)`` of each slot, resolved through the page table —
     the paged analogue of ``cache_update_window`` (T = 1 of
-    ``cache_update``).  A window may span a page boundary; each row
-    scatters to its own (page, offset).  Rows whose logical block falls
-    off the table clamp to the slot's last table entry — retired slots'
-    tables are reset to the reserved garbage page 0, so their frozen
-    in-chunk writes can never corrupt a reallocated page."""
-    ps = pool.shape[1]
-    b, t = new.shape[:2]
+    ``cache_update``).  Only the new rows are written, each at its own
+    ``(layer, head, page, offset)``, so a pool that is donated or
+    carried through a loop is updated in place.  A window may span a
+    page boundary.  Rows whose logical block falls off the table clamp
+    to the slot's last table entry — retired slots' tables are reset to
+    the reserved garbage page 0, so their frozen in-chunk writes can
+    never corrupt a reallocated page."""
+    ps = pool.shape[3]
+    b, t, kvh = new.shape[:3]
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         pos = jnp.full((b,), pos, jnp.int32)
     rows = pos[:, None] + jnp.arange(t)[None, :]              # (B, T)
     blk = jnp.minimum(rows // ps, pages.shape[1] - 1)
     page = jnp.take_along_axis(pages, blk, axis=1)            # (B, T)
-    return pool.at[page, rows % ps].set(new.astype(pool.dtype))
+    # one index per (slot, token, head), so each update is a single
+    # D-row: the pool keeps its row-major layout, the one the kernel reads
+    head = jnp.arange(kvh)[None, None, :]
+    return pool.at[layer, head, page[..., None],
+                   (rows % ps)[..., None]].set(new.astype(pool.dtype))
 
 
-def gqa_decode_paged(x, p, cfg, cache, pos, pages):
+def _paged_attend(q, k, v, cfg, cache, pos, pages, layer, kernel, ref):
+    """Write this layer's new K/V rows into the stacked pools and
+    attend over them (the shared tail of paged decode and verify)."""
+    k_pool = paged_insert_window(cache["k"], k, pages, pos, layer)
+    v_pool = paged_insert_window(cache["v"], v, pages, pos, layer)
+    k_pool = shard(k_pool, None, "kv_heads", None, None, None)
+    v_pool = shard(v_pool, None, "kv_heads", None, None, None)
+    if cfg.use_pallas:
+        o = kernel(q, k_pool, v_pool, pages, pos, layer,
+                   interpret=cfg.pallas_interpret)
+    else:
+        o = ref(q, paged_view(k_pool, pages, layer),
+                paged_view(v_pool, pages, layer), pos)
+    return o, {"k": k_pool, "v": v_pool}
+
+
+def gqa_decode_paged(x, p, cfg, cache, pos, pages, layer):
     """One-token decode against a paged KV pool.
 
-    cache = dict(k, v) with pool leaves (P, page_size, KVH, D) shared
-    by all slots; ``pages`` (B, NB) is the per-slot page table and
-    ``pos`` the per-slot depth vector.  The gather/scatter indirection
+    cache = dict(k, v) with the stacked pools (L, KVH, P, page_size, D)
+    shared by all slots and all layers; ``layer`` picks this layer's
+    pool, ``pages`` (B, NB) is the per-slot page table and ``pos`` the
+    per-slot depth vector.  Returns the pools with this layer's rows
+    written, nothing else touched.  The gather/scatter indirection
     preserves the contiguous layout's values bit-for-bit, so greedy
     output is token-identical to ``gqa_decode`` for any page
     permutation.  With ``cfg.use_pallas`` attention dispatches to the
-    scalar-prefetch paged kernel (the table drives the KV block index
-    maps); the jnp gather path below is its CPU-exact analogue.
+    scalar-prefetch paged kernel (the table and layer drive the KV
+    block index maps); the jnp gather path is its CPU-exact analogue.
     """
+    from repro.kernels.decode_attention.ops import paged_decode_attention
     b = x.shape[0]
     q, k, v = _proj_qkv(x, p, cfg)
     pos = jnp.asarray(pos)
     poss = pos[:, None] if pos.ndim == 1 else jnp.full((1,), pos)
     q = rope(q, poss, cfg.rope_theta)
     k = rope(k, poss, cfg.rope_theta)
-    k_pool = paged_insert_window(cache["k"], k, pages, pos)
-    v_pool = paged_insert_window(cache["v"], v, pages, pos)
-    k_pool = shard(k_pool, None, None, "kv_heads", None)
-    v_pool = shard(v_pool, None, None, "kv_heads", None)
-    if cfg.use_pallas:
-        from repro.kernels.decode_attention.ops import paged_decode_attention
-        o = paged_decode_attention(q, k_pool, v_pool, pages, pos,
-                                   interpret=cfg.pallas_interpret)
-    else:
-        o = decode_attention_jnp(q, paged_view(k_pool, pages),
-                                 paged_view(v_pool, pages), pos)
+    o, kvc = _paged_attend(q, k, v, cfg, cache, pos, pages, layer,
+                           paged_decode_attention, decode_attention_jnp)
     o = tp_psum(o.reshape(b, 1, -1) @ p["wo"])
-    return o, {"k": k_pool, "v": v_pool}
+    return o, kvc
 
 
-def gqa_verify_paged(x, p, cfg, cache, pos, pages):
+def gqa_verify_paged(x, p, cfg, cache, pos, pages, layer):
     """Multi-token verify against a paged KV pool (speculative window,
     and the suffix prefill of a prefix-cache hit).
 
     x: (B, T, d); window rows ``[pos, pos + T)`` scatter through the
-    page table and may span page boundaries.  Rollback is unchanged
-    from the contiguous path: rejected tokens' rows go stale and the
-    next window overwrites them in place — the table is only re-read,
-    never rewritten, so crossing a boundary needs no special casing.
+    page table into layer ``layer`` of the stacked pools and may span
+    page boundaries.  Rollback is unchanged from the contiguous path:
+    rejected tokens' rows go stale and the next window overwrites them
+    in place — the table is only re-read, never rewritten, so crossing
+    a boundary needs no special casing.
     """
+    from repro.kernels.decode_attention.ops import paged_verify_attention
     b, t, _ = x.shape
     q, k, v = _proj_qkv(x, p, cfg)
     pos = jnp.asarray(pos)
@@ -524,19 +546,10 @@ def gqa_verify_paged(x, p, cfg, cache, pos, pages):
     positions = pos[:, None] + jnp.arange(t)[None, :]         # (B, T)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    k_pool = paged_insert_window(cache["k"], k, pages, pos)
-    v_pool = paged_insert_window(cache["v"], v, pages, pos)
-    k_pool = shard(k_pool, None, None, "kv_heads", None)
-    v_pool = shard(v_pool, None, None, "kv_heads", None)
-    if cfg.use_pallas:
-        from repro.kernels.decode_attention.ops import paged_verify_attention
-        o = paged_verify_attention(q, k_pool, v_pool, pages, pos,
-                                   interpret=cfg.pallas_interpret)
-    else:
-        o = verify_attention_jnp(q, paged_view(k_pool, pages),
-                                 paged_view(v_pool, pages), pos)
+    o, kvc = _paged_attend(q, k, v, cfg, cache, pos, pages, layer,
+                           paged_verify_attention, verify_attention_jnp)
     o = tp_psum(o.reshape(b, t, -1) @ p["wo"])
-    return o, {"k": k_pool, "v": v_pool}
+    return o, kvc
 
 
 # ----------------------------------------------------------------------

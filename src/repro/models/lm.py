@@ -146,10 +146,12 @@ class LM:
     # ------------------------------------------------------------------
     # Block application
     # ------------------------------------------------------------------
-    def _apply_block(self, x, bp, kind, mode, cache, pos, pages=None):
+    def _apply_block(self, x, bp, kind, mode, cache, pos, pages=None,
+                     layer=None):
         """Returns (x, new_cache, aux).  ``pages`` (B, NB) switches the
         attention decode/verify paths to the paged KV pool — the cache
-        leaves are then shared physical pages, not per-slot rows."""
+        leaves are then the stacked pools of shared physical pages of
+        every layer, and ``layer`` says which of them this block owns."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
@@ -195,7 +197,7 @@ class LM:
             elif mode == "verify" and pages is not None:
                 o, kvc = L.gqa_verify_paged(
                     h, bp["attn"], cfg,
-                    {"k": cache["k"], "v": cache["v"]}, pos, pages)
+                    {"k": cache["k"], "v": cache["v"]}, pos, pages, layer)
                 new_cache = dict(cache, **kvc)
             elif mode == "verify":
                 o, kvc = L.gqa_verify(h, bp["attn"], cfg,
@@ -212,7 +214,7 @@ class LM:
             elif pages is not None:
                 o, kvc = L.gqa_decode_paged(
                     h, bp["attn"], cfg,
-                    {"k": cache["k"], "v": cache["v"]}, pos, pages)
+                    {"k": cache["k"], "v": cache["v"]}, pos, pages, layer)
                 new_cache = dict(cache, **kvc)
             else:
                 o, kvc = L.gqa_decode(h, bp["attn"], cfg,
@@ -271,17 +273,17 @@ class LM:
 
     def _run_stack(self, params, x, mode, cache, pos, pages=None):
         """Run all blocks; returns (x, new_cache, aux_mean).  ``pages``
-        is closed over by the scan body: one page table serves every
-        layer (the pool leaves are stacked per layer, the table is
-        not)."""
+        (the paged KV pool's table) takes the paged stack."""
         cfg = self.cfg
+        if pages is not None:
+            return self._run_paged_stack(params, x, mode, cache, pos, pages)
 
         def scan_group(x, stacked, kinds_key, cache_g):
             """Scan homogeneous stacked blocks (cache as scan xs/ys)."""
             def body(carry, xs):
                 bp, c = xs
                 xx, nc, aux = self._apply_block(carry, bp, kinds_key,
-                                                mode, c, pos, pages)
+                                                mode, c, pos)
                 return xx, (nc, aux)
 
             body = self._maybe_remat(body) if mode == "train" else body
@@ -349,6 +351,36 @@ class LM:
         kind = self._layer_kinds()[0]
         x, nc, aux = scan_group(x, params["blocks"], kind, cache["blocks"])
         return x, {"blocks": nc}, aux
+
+    def _run_paged_stack(self, params, x, mode, cache, pos, pages):
+        """The paged homogeneous stack: the stacked pools ride the layer
+        loop as carry, and each layer writes its new rows into them at
+        its own index.  No layer's pool is sliced out or stacked anew,
+        so under a donated or loop-carried state the pools stay in one
+        buffer, updated in place.  One page table serves every layer."""
+        kind = self._layer_kinds()[0]
+        stacked = params["blocks"]
+
+        def body(carry, xs):
+            xx, c = carry
+            bp, layer = xs
+            xx, c, aux = self._apply_block(xx, bp, kind, mode, c, pos,
+                                           pages, layer)
+            return (xx, c), aux
+
+        n = self.cfg.n_layers
+        if not self.cfg.scan_layers or self.cfg.unroll_scans:
+            carry, aux_l = (x, cache["blocks"]), []
+            for i in range(n):
+                carry, aux_i = body(
+                    carry, (jax.tree.map(lambda a: a[i], stacked), i))
+                aux_l.append(aux_i)
+            (x, nc), aux = carry, jnp.stack(aux_l)
+        else:
+            (x, nc), aux = jax.lax.scan(
+                body, (x, cache["blocks"]),
+                (stacked, jnp.arange(n, dtype=jnp.int32)))
+        return x, {"blocks": nc}, jnp.mean(aux)
 
     # ------------------------------------------------------------------
     # Caches
@@ -449,9 +481,10 @@ class LM:
     def paged_cache_specs(self, batch: int, n_pages: int, page_size: int,
                           pages_per_slot: int):
         """ShapeDtypeStruct tree for the paged engine state: per-layer
-        K/V page *pools* shared by all slots, a per-slot ``pos`` vector,
-        and the per-slot page table.  Only homogeneous dense-attention
-        stacks are supported — paged decode needs random-access KV."""
+        K/V page *pools* shared by all slots, stacked as (L, KVH, P,
+        page_size, D), a per-slot ``pos`` vector, and the per-slot page
+        table.  Only homogeneous dense-attention stacks are supported —
+        paged decode needs random-access KV."""
         cfg = self.cfg
         kinds = set(self._layer_kinds())
         if cfg.family not in ("dense", "moe") or not all(
@@ -460,8 +493,9 @@ class LM:
                 f"paged KV needs a homogeneous attention stack; family "
                 f"{cfg.family!r} has layer kinds {sorted(kinds)}")
         dt = jnp.dtype(cfg.dtype)
+        # KV-head-major, the layout the paged kernels stream
         pool = jax.ShapeDtypeStruct(
-            (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
+            (cfg.n_layers, cfg.n_kv_heads, n_pages, page_size,
              cfg.head_dim), dt)
         return {"layers": {"blocks": {"k": pool, "v": pool}},
                 "pos": jax.ShapeDtypeStruct((batch,), jnp.int32),
@@ -472,7 +506,7 @@ class LM:
         """PartitionSpecs for ``paged_cache_specs``: pools partitioned
         by KV head (the TP split), table and positions replicated."""
         from repro.parallel.sharding import logical_pspec
-        pool = logical_pspec((None, None, None, "kv_heads", None), rules)
+        pool = logical_pspec((None, "kv_heads", None, None, None), rules)
         return {"layers": {"blocks": {"k": pool, "v": pool}},
                 "pos": logical_pspec(("batch",), rules),
                 "pages": logical_pspec(("batch", None), rules)}

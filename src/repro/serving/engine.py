@@ -57,6 +57,15 @@ def _bounded_while(n_steps: int, live, body, init):
     return jax.lax.while_loop(cond, step, (0, init))[1]
 
 
+def _scatter_pages(pool, blocks, pages):
+    """Write page-shaped blocks (L, NB, page_size, KVH, D) at physical
+    ``pages`` (NB,) of a stacked pool (L, KVH, P, page_size, D): the
+    small blocks are transposed to the pool's KV-head-major layout, the
+    pool is written in place."""
+    return pool.at[:, :, pages].set(
+        blocks.transpose(0, 3, 1, 2, 4).astype(pool.dtype))
+
+
 @dataclasses.dataclass
 class Request:
     """One serving request: the contract every engine fills in.
@@ -434,8 +443,8 @@ class ContinuousBatchingEngine:
 
             def scatter(pool, small):
                 blocks = small[:, 0].reshape(
-                    small.shape[0], pps, ps, *pool.shape[3:])
-                return pool.at[:, pages].set(blocks.astype(pool.dtype))
+                    small.shape[0], pps, ps, *small.shape[3:])
+                return _scatter_pages(pool, blocks, pages)
 
             layers = jax.tree.map(scatter, cache["layers"],
                                   one["layers"])
@@ -543,7 +552,8 @@ class ContinuousBatchingEngine:
 
         ``blocks``: per-layer K/V trees of shape (L, NB, page, kvh, dh)
         computed by a prefill replica; they are scattered at physical
-        ``pages`` (the (NB,) prompt pages) of this engine's pool.
+        ``pages`` (the (NB,) prompt pages) of this engine's pool,
+        transposed there to the pool's KV-head-major layout.
         ``row`` is the slot's full padded table row, ``n_tokens`` the
         prompt length, ``tok0`` the first token the prefill replica
         already emitted.  After this the slot decodes exactly as if it
@@ -551,10 +561,9 @@ class ContinuousBatchingEngine:
         """
         cache = state["cache"]
 
-        def scatter(pool, small):
-            return pool.at[:, pages].set(small.astype(pool.dtype))
-
-        layers = jax.tree.map(scatter, cache["layers"], blocks)
+        layers = jax.tree.map(
+            lambda pool, small: _scatter_pages(pool, small, pages),
+            cache["layers"], blocks)
         pos = cache["pos"].at[slot].set(n_tokens.astype(jnp.int32))
         table = cache["pages"].at[slot].set(row)
         return dict(

@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.sharding import (make_tp_rules, param_pspecs,
-                                     tp_ctx, tp_local_config)
+from repro.parallel.sharding import (logical_pspec, make_tp_rules,
+                                     param_pspecs, tp_ctx,
+                                     tp_local_config)
 from repro.serving.engine import ContinuousBatchingEngine
 
 
@@ -131,6 +132,10 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
         # are replicated host-managed indices
         if kw.get("kv_page_size"):
             self._cache_specs = model.paged_cache_pspecs(rules)
+            # handed-off blocks (L, NB, page, kvh, dh) split on their
+            # own KV-head axis, like the pool pages they are written to
+            blk = logical_pspec((None, None, None, "kv_heads", None), rules)
+            self._block_specs = {"blocks": {"k": blk, "v": blk}}
         else:
             self._cache_specs = model.cache_pspecs(rules,
                                                    per_slot_pos=True)
@@ -219,7 +224,7 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
         base = super()._install_slot_impl
         return self._shard_mapped(
             base,
-            in_specs=(self._state_specs, self._cache_specs["layers"])
+            in_specs=(self._state_specs, self._block_specs)
             + (P(),) * 6,
             out_specs=self._state_specs,
         )(state, blocks, tok0, slot, pages, row, n_tokens, budget)

@@ -48,15 +48,39 @@ def test_pagepool_refcount_conservation(ops, n_pages):
         assert pool.refcount[page] > 1     # shared pages still owned
 
 
+N_LAYERS = 4
+LAYERS = [0, N_LAYERS // 2, N_LAYERS - 1]      # first, middle, last
+
+
+def _stacked_pools(rng, caches, tables, n_pages, ps, layer):
+    """Random stacked pools (L, KVH, P, ps, D), one per contiguous cache
+    (B, S, KVH, D) in ``caches``, whose layer ``layer`` holds that
+    cache's blocks at the table's physical pages."""
+    b, s, kvh, d = caches[0].shape
+    pools = []
+    for c in caches:
+        pool = rng.standard_normal(
+            (N_LAYERS, kvh, n_pages, ps, d)).astype(np.float32)
+        for bb in range(b):
+            for i in range(s // ps):
+                pool[layer, :, tables[bb, i]] = np.asarray(
+                    c[bb, i * ps:(i + 1) * ps]).transpose(1, 0, 2)
+        pools.append(jnp.asarray(pool))
+    return pools
+
+
+@pytest.mark.parametrize("layer", LAYERS)
 @given(seed=st.integers(0, 2 ** 31 - 1),
        pos=st.lists(st.integers(0, 31), min_size=2, max_size=2))
 @settings(max_examples=8, deadline=None)
-def test_paged_attention_permutation_bit_identity(seed, pos):
+def test_paged_attention_permutation_bit_identity(layer, seed, pos):
     """Any page-table permutation of the KV pool is bit-identical to
     the contiguous layout at equal block size — the page indirection
-    changes only *where* a block lives, never the arithmetic."""
+    changes only *where* a block lives, never the arithmetic — at any
+    layer of the stacked pool, and matches the jnp reference."""
     from repro.kernels.decode_attention.ops import (
         decode_attention, paged_decode_attention)
+    from repro.models.layers import decode_attention_jnp
 
     b, h, kvh, d, ps, nb = 2, 4, 2, 16, 8, 4
     n_pages = b * nb + 1
@@ -70,19 +94,82 @@ def test_paged_attention_permutation_bit_identity(seed, pos):
 
     tables = rng.permutation(np.arange(1, n_pages))[:b * nb] \
         .reshape(b, nb)
-    k_pool = jnp.asarray(rng.standard_normal((n_pages, ps, kvh, d)),
-                         jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((n_pages, ps, kvh, d)),
-                         jnp.float32)
-    for bb in range(b):
-        for i in range(nb):
-            k_pool = k_pool.at[tables[bb, i]].set(
-                kc[bb, i * ps:(i + 1) * ps])
-            v_pool = v_pool.at[tables[bb, i]].set(
-                vc[bb, i * ps:(i + 1) * ps])
+    k_pool, v_pool = _stacked_pools(rng, (kc, vc), tables, n_pages, ps,
+                                    layer)
 
     ref = decode_attention(q, kc, vc, posv, block_k=ps, interpret=True)
     out = paged_decode_attention(q, k_pool, v_pool,
                                  jnp.asarray(tables, jnp.int32), posv,
-                                 interpret=True)
+                                 jnp.int32(layer), interpret=True)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(decode_attention_jnp(q, kc, vc, posv)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@given(seed=st.integers(0, 2 ** 31 - 1),
+       pos=st.lists(st.integers(0, 29), min_size=2, max_size=2))
+@settings(max_examples=8, deadline=None)
+def test_paged_verify_permutation_bit_identity(layer, seed, pos):
+    """The verify window (T = 3, crossing page boundaries) through the
+    page table at any layer of the stacked pool is bit-identical to the
+    contiguous verify kernel and matches the jnp reference."""
+    from repro.kernels.decode_attention.ops import (
+        paged_verify_attention, verify_attention)
+    from repro.models.layers import verify_attention_jnp
+
+    b, t, h, kvh, d, ps, nb = 2, 3, 4, 2, 16, 8, 4
+    n_pages = b * nb + 3
+    rng = np.random.default_rng(seed)
+    kc = jnp.asarray(rng.standard_normal((b, nb * ps, kvh, d)),
+                     jnp.float32)
+    vc = jnp.asarray(rng.standard_normal((b, nb * ps, kvh, d)),
+                     jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+    posv = jnp.asarray(pos, jnp.int32)
+
+    tables = rng.permutation(np.arange(1, n_pages))[:b * nb] \
+        .reshape(b, nb)
+    k_pool, v_pool = _stacked_pools(rng, (kc, vc), tables, n_pages, ps,
+                                    layer)
+
+    ref = verify_attention(q, kc, vc, posv, block_k=ps, interpret=True)
+    out = paged_verify_attention(q, k_pool, v_pool,
+                                 jnp.asarray(tables, jnp.int32), posv,
+                                 jnp.int32(layer), interpret=True)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(verify_attention_jnp(q, kc, vc, posv)),
+        rtol=1e-5, atol=1e-5)
+
+
+@given(seed=st.integers(0, 2 ** 31 - 1),
+       pos=st.lists(st.integers(0, 31), min_size=3, max_size=3),
+       layer=st.integers(0, N_LAYERS - 1), t=st.sampled_from([1, 3]))
+@settings(max_examples=25, deadline=None)
+def test_paged_insert_window_writes_only_its_rows(seed, pos, layer, t):
+    """``paged_insert_window`` writes each new row at exactly
+    ``(layer, head, page, offset)`` of the stacked pool and leaves every
+    other row of every layer bit-identical; a window that runs off the
+    table clamps to the slot's last page."""
+    from repro.models.layers import paged_insert_window
+
+    b, kvh, d, ps, nb = 3, 2, 4, 8, 4
+    n_pages = b * nb + 1
+    rng = np.random.default_rng(seed)
+    pool = rng.standard_normal(
+        (N_LAYERS, kvh, n_pages, ps, d)).astype(np.float32)
+    new = rng.standard_normal((b, t, kvh, d)).astype(np.float32)
+    tables = rng.permutation(np.arange(1, n_pages)).reshape(b, nb)
+
+    out = paged_insert_window(jnp.asarray(pool), jnp.asarray(new),
+                              jnp.asarray(tables, jnp.int32),
+                              jnp.asarray(pos, jnp.int32), layer)
+    want = pool.copy()
+    for bb in range(b):
+        for j in range(t):
+            row = pos[bb] + j
+            page = tables[bb, min(row // ps, nb - 1)]
+            want[layer, :, page, row % ps] = new[bb, j]
+    np.testing.assert_array_equal(np.asarray(out), want)

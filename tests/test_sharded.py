@@ -177,9 +177,9 @@ def test_params_and_kv_pool_created_in_their_shards():
         assert eng.params["blocks"]["attn"]["wq"] is wq
         pool = eng.state["cache"]["layers"]["blocks"]["k"]
         assert pool.sharding == NamedSharding(
-            mesh, P(None, None, None, "model", None)), pool.sharding
+            mesh, P(None, "model", None, None, None)), pool.sharding
         assert len(pool.addressable_shards) == 4
-        assert pool.addressable_shards[0].data.shape[3] == 1
+        assert pool.addressable_shards[0].data.shape[1] == 1
         print("PLACED-OK")
     """)
     assert "PLACED-OK" in out
