@@ -17,7 +17,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
-MEMORY_SPACES = ("vmem", "smem", "any")
+MEMORY_SPACES = ("vmem", "smem", "any", "semaphore")
 
 # itemsize table so contracts stay importable without jax
 _DTYPE_BYTES = {
@@ -68,7 +68,8 @@ class OperandSpec:
 
 @dataclasses.dataclass(frozen=True)
 class ScratchSpec:
-    """One scratch allocation (VMEM/SMEM), persistent across the grid."""
+    """One scratch allocation (VMEM/SMEM, or DMA semaphores), persistent
+    across the grid."""
 
     shape: tuple
     dtype: str

@@ -177,7 +177,7 @@ def check_instance(contract: KernelContract, case: dict,
     for sc in inst.scratch:
         if sc.memory_space == "smem":
             smem += sc.nbytes()
-        else:
+        elif sc.memory_space == "vmem":
             vmem += sc.nbytes()
     if vmem > contract.vmem_budget_bytes:
         finding("KRN005",

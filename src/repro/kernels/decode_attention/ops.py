@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.contracts import (
-    KernelContract, KernelInstance, OperandSpec, ScratchSpec,
+    KernelContract, KernelInstance, OperandSpec, ScratchSpec, dtype_bytes,
 )
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_kernel, paged_decode_attention_kernel,
@@ -97,28 +97,60 @@ def verify_attention(q, k_cache, v_cache, pos, *,
         b, t, h, d)
 
 
+# One half of the paged kernel's double buffer holds about this much K
+# and V: large enough that a grid step's fixed cost is small beside its
+# copies, small enough that both halves sit well inside the default
+# scoped VMEM.
+PAGED_BLOCK_BYTES = 2 * 2 ** 20
+
+
+def fit_pages_per_block(kvh: int, page_size: int, d: int, nb: int,
+                        itemsize: int) -> int:
+    """Table entries per grid step of the paged decode kernel: the most
+    pages whose K and V, over the ``kvh`` (shard-local) heads, fit in
+    ``PAGED_BLOCK_BYTES``, at least one, dividing the ``nb`` pages of a
+    slot."""
+    page = 2 * kvh * page_size * d * itemsize
+    ppb = max(1, min(nb, PAGED_BLOCK_BYTES // page))
+    while nb % ppb:
+        ppb -= 1
+    return ppb
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pool, v_pool, pages, pos, layer, *,
                            interpret: bool = False):
     """Page-table-indirect decode attention in model layout.
 
     q: (B, 1, H, D); pools: (L, KVH, P, page_size, D), the stacked
-    per-layer pools of shared physical pages, KV-head-major as the
-    kernel streams them; pages: (B, NB) int32 per-slot page table; pos:
-    () or (B,) int32; layer: () int32, the layer to attend over.
-    Returns (B, 1, H, D).  The pools are read where they lie: the table
-    and the layer are scalar-prefetched to drive the KV block index
-    maps, so neither a layer slice nor a transpose of the pool is ever
-    made.  Values are bit-identical to ``decode_attention`` on the
-    equivalent contiguous cache for any page permutation.
+    per-layer pools of shared physical pages, KV-head-major; pages:
+    (B, NB) int32 per-slot page table; pos: () or (B,) int32; layer: ()
+    int32, the layer to attend over.  Returns (B, 1, H, D).
+
+    The pools stay where they lie, in HBM: one grid step takes one slot
+    and ``fit_pages_per_block`` consecutive table entries, for all of
+    the (shard-local) KV heads, and copies only the slot's live pages,
+    ``pos // page_size + 1`` of them, double-buffered so the next
+    block's copies run under this block's arithmetic.  Page 0 of the
+    pool is reserved (``EMPTY_PAGE``): a slot whose table row starts
+    with it holds no request, costs no copy and no arithmetic, and
+    returns zeros.  The block size comes from the shapes alone (heads,
+    page size, head width, table width), so the same call adapts to a
+    whole model's heads on one chip and to one head a chip under
+    tensor parallelism.  A live slot's values are bit-identical to
+    ``decode_attention`` at ``block_k = page_size`` on the equivalent
+    contiguous cache, for any placement of its pages in the pool.
     """
     b, _, h, d = q.shape
-    kvh = k_pool.shape[1]
+    kvh, page_size = k_pool.shape[1], k_pool.shape[3]
     g = h // kvh
     qr = q[:, 0].reshape(b, kvh, g, d).reshape(b * kvh, g, d)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    ppb = fit_pages_per_block(kvh, page_size, d, pages.shape[1],
+                              k_pool.dtype.itemsize)
     o = paged_decode_attention_kernel(qr, k_pool, v_pool, pages, pos,
-                                      layer, interpret=interpret)
+                                      layer, pages_per_block=ppb,
+                                      interpret=interpret)
     return o.reshape(b, h, d)[:, None]
 
 
@@ -230,7 +262,8 @@ def _paged_table(case):
 
 def _paged_kv_operands(case, dt):
     """The stacked K and V pools (L, KVH, P, page_size, D) as the paged
-    kernels block them: one page of one head of layer ``layer``."""
+    verify kernel blocks them: one page of one head of layer
+    ``layer``."""
     kvh, d = case["kvh"], case["d"]
     ps, n_pages = case["page_size"], case["n_pages"]
     n_layers, layer = case["n_layers"], case["layer"]
@@ -243,29 +276,45 @@ def _paged_kv_operands(case, dt):
 
 
 def _paged_decode_contract(case):
+    """One slot and ``fit_pages_per_block`` table entries per grid step,
+    all KV heads; the pools stay in HBM (``any``) and are copied by hand
+    into a double buffer guarded by one DMA semaphore per half and pool.
+    Both grid axes are sequential: each step prefetches the next live
+    block, which may belong to the next slot."""
     b, nb = case["b"], case["nb"]
     h, kvh, d = case["h"], case["kvh"], case["d"]
+    ps, n_pages, n_layers = case["page_size"], case["n_pages"], \
+        case["n_layers"]
     g = h // kvh
-    bh = b * kvh
     dt = case.get("dtype", "bfloat16")
+    ppb = fit_pages_per_block(kvh, ps, d, nb, dtype_bytes(dt))
+    pool = (n_layers, kvh, n_pages, ps, d)
+    slot = lambda s, i: (s, 0, 0, 0)
     return KernelInstance(
-        grid=(bh, nb),
-        semantics=("parallel", "arbitrary"),
+        grid=(b, nb // ppb),
+        semantics=("arbitrary", "arbitrary"),
         inputs=(
             OperandSpec("pages", (b, nb), "int32", memory_space="smem"),
             OperandSpec("pos", (b,), "int32", memory_space="smem"),
             OperandSpec("layer", (1,), "int32", memory_space="smem"),
-            OperandSpec("q", (bh, g, d), dt, block=(1, g, d),
-                        index_map=lambda bb, ik: (bb, 0, 0)),
-        ) + _paged_kv_operands(case, dt),
+            OperandSpec("q", (b, kvh, g, d), dt, block=(1, kvh, g, d),
+                        index_map=slot),
+            OperandSpec("k", pool, dt, memory_space="any"),
+            OperandSpec("v", pool, dt, memory_space="any"),
+        ),
         outputs=(
-            OperandSpec("o", (bh, g, d), dt, block=(1, g, d),
-                        index_map=lambda bb, ik: (bb, 0, 0)),
+            OperandSpec("o", (b, kvh, g, d), dt, block=(1, kvh, g, d),
+                        index_map=slot),
         ),
         scratch=(
-            ScratchSpec((g, 1), "float32"),
-            ScratchSpec((g, 1), "float32"),
-            ScratchSpec((g, d), "float32"),
+            ScratchSpec((2, kvh, ppb, ps, d), dt),
+            ScratchSpec((2, kvh, ppb, ps, d), dt),
+            ScratchSpec((2, 2), "int32", memory_space="semaphore"),
+            ScratchSpec((1,), "int32", memory_space="smem"),
+            ScratchSpec((b + 1,), "int32", memory_space="smem"),
+            ScratchSpec((kvh, g, 1), "float32"),
+            ScratchSpec((kvh, g, 1), "float32"),
+            ScratchSpec((kvh, g, d), "float32"),
         ),
     )
 
@@ -329,14 +378,17 @@ CONTRACTS = (
         name="paged_decode_attention",
         build=_paged_decode_contract,
         cases=(
-            # serving shape: 8 slots x 32 pages of 128 tokens (max_len
-            # 4096), pool sized one-page-per-slot-worth + garbage page,
-            # read at the last of 28 stacked layers
-            {"b": 8, "nb": 32, "page_size": 128, "n_pages": 257,
-             "n_layers": 28, "layer": 27, "h": 16, "kvh": 4, "d": 128},
+            # qwen3-1.7b served: 16 slots x 16 pages of 128 tokens, 8 KV
+            # heads of 128 -> 4 pages a step
+            {"b": 16, "nb": 16, "page_size": 128, "n_pages": 257,
+             "n_layers": 28, "h": 16, "kvh": 8, "d": 128},
+            # yi-9b at TP=4, one chip's share: 64 slots, 1 KV head ->
+            # all 16 pages in one step
+            {"b": 64, "nb": 16, "page_size": 128, "n_pages": 1025,
+             "n_layers": 48, "h": 8, "kvh": 1, "d": 128},
             # small-page CI shape (matches the engine parity tests)
             {"b": 3, "nb": 8, "page_size": 128, "n_pages": 25,
-             "n_layers": 2, "layer": 0, "h": 8, "kvh": 2, "d": 64},
+             "n_layers": 2, "h": 8, "kvh": 2, "d": 64},
         ),
         dtype_groups=(("q", "k", "v", "o"),),
     ),

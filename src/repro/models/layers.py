@@ -510,8 +510,9 @@ def gqa_decode_paged(x, p, cfg, cache, pos, pages, layer):
     preserves the contiguous layout's values bit-for-bit, so greedy
     output is token-identical to ``gqa_decode`` for any page
     permutation.  With ``cfg.use_pallas`` attention dispatches to the
-    scalar-prefetch paged kernel (the table and layer drive the KV
-    block index maps); the jnp gather path is its CPU-exact analogue.
+    paged kernel, which copies each slot's live pages of this layer out
+    of the pools in place (page 0 of the table marks an empty slot);
+    the jnp gather path is its CPU-exact analogue.
     """
     from repro.kernels.decode_attention.ops import paged_decode_attention
     b = x.shape[0]

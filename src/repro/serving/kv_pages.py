@@ -15,7 +15,9 @@ out by ``alloc``.  Retired slots keep decoding inside the frozen
 on-device chunk loop (their lane is masked, but the cache scatter
 still happens); resetting a retired slot's page-table row to 0 aims
 those dead writes at the garbage page instead of at pages that may
-since have been reallocated to another request.
+since have been reallocated to another request.  The paged decode
+kernel reads a row that starts with it as a slot holding no request
+(its ``EMPTY_PAGE``) and skips the slot.
 """
 from __future__ import annotations
 

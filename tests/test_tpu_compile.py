@@ -4,7 +4,9 @@ The TPU compiler is installed even where no chip is attached: it
 compiles for a described ``v5e:2x2`` topology, and refuses what the
 chip would refuse (unaligned slices, too much fast memory, programs
 that do not fit).  Shapes are qwen3-1.7b's (16 query / 8 KV heads of
-128, 28 layers) with 8 slots of 4096 tokens, paged as 257 pages of 128.
+128, 28 layers) with 8 slots of 4096 tokens, paged as 257 pages of 128;
+the paged decode kernel also at yi-9b's share of one chip at TP=4 (64
+slots of 16 pages, 8 query heads over 1 KV head, 48 layers).
 Nothing runs; these guard the compiled kernels at real widths, and the
 whole decode-chunk program of the served configuration against copies
 of its KV page pool.
@@ -80,14 +82,24 @@ def test_decode_attention_compiles_for_v5e(one_chip):
              _sds((B,), jnp.int32, one_chip))
 
 
-def test_paged_decode_attention_compiles_for_v5e(one_chip):
+# (slots, pages a slot, query heads, KV heads, layers) of the paged
+# decode kernel on one chip: qwen3-1.7b's as above, and yi-9b's share
+# at TP=4 (64 slots of 2048 tokens, 8 query heads over 1 KV head)
+PAGED_SHAPES = {"qwen3": (B, S // PAGE, H, KVH, LAYERS),
+                "yi-9b-tp4": (64, 16, 8, 1, 48)}
+
+
+@pytest.mark.parametrize("shape", sorted(PAGED_SHAPES))
+def test_paged_decode_attention_compiles_for_v5e(one_chip, shape):
+    b, nb, h, kvh, layers = PAGED_SHAPES[shape]
+    pool = (layers, kvh, b * nb + 1, PAGE, D)
     bf16 = jnp.bfloat16
     _compile(partial(paged_decode_attention, interpret=False),
-             _sds((B, 1, H, D), bf16, one_chip),
-             _sds(POOL, bf16, one_chip),
-             _sds(POOL, bf16, one_chip),
-             _sds((B, S // PAGE), jnp.int32, one_chip),
-             _sds((B,), jnp.int32, one_chip),
+             _sds((b, 1, h, D), bf16, one_chip),
+             _sds(pool, bf16, one_chip),
+             _sds(pool, bf16, one_chip),
+             _sds((b, nb), jnp.int32, one_chip),
+             _sds((b,), jnp.int32, one_chip),
              _sds((), jnp.int32, one_chip))
 
 
