@@ -1,81 +1,5 @@
-"""Shared helpers: dry-run result loading + energy derivation.
-
-Benchmarks read the compiled dry-run artifacts (experiments/dryrun/*.json)
-when present and fall back to analytic StepWork estimates otherwise, so
-``python -m benchmarks.run`` works on a fresh checkout.
-"""
+"""Shared helpers: CSV rows and interleaved best-of measurement."""
 from __future__ import annotations
-
-import glob
-import json
-import os
-
-from repro.core.power_model import StepWork, SystemPowerModel, roofline
-from repro.hw import DATACENTER_V5E, SystemSpec
-
-DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
-                          "dryrun")
-
-
-def load_cell(arch: str, shape: str, mesh: str = "pod",
-              tag: str = "") -> dict | None:
-    suffix = f"__{tag}" if tag else ""
-    p = os.path.join(DRYRUN_DIR, f"{arch}__{shape}__{mesh}{suffix}.json")
-    if not os.path.exists(p):
-        return None
-    with open(p) as f:
-        return json.load(f)
-
-
-def all_cells(tag: str = "") -> list[dict]:
-    out = []
-    for p in sorted(glob.glob(os.path.join(DRYRUN_DIR, "*.json"))):
-        name = os.path.basename(p)[:-5]
-        parts = name.split("__")
-        want_tag = parts[3] if len(parts) > 3 else ""
-        if want_tag != tag:
-            continue
-        with open(p) as f:
-            out.append(json.load(f))
-    return out
-
-
-def work_from_cell(rec: dict, int8: bool = False) -> StepWork:
-    """Per-chip StepWork from a dry-run record.  ``int8``: model the
-    quantized deployment (half the matmul bytes, int8 MXU path)."""
-    flops = rec["flops"]
-    hbm = rec["hbm_bytes"]
-    if int8:
-        return StepWork(flops=flops, hbm_bytes=hbm / 2,
-                        ici_bytes=rec["coll_bytes"] / 2, flops_int8=flops)
-    return StepWork(flops=flops, hbm_bytes=hbm,
-                    ici_bytes=rec["coll_bytes"])
-
-
-def cell_energy(rec: dict, system: SystemSpec = DATACENTER_V5E,
-                int8: bool = False) -> dict:
-    """Seconds + Joules for one executed step of a dry-run cell."""
-    n = rec["n_devices"]
-    model = SystemPowerModel(system, n)
-    work = work_from_cell(rec, int8)
-    rt = roofline(work, system.chip)
-    step_s = rt.step_s
-    watts = model.system_watts(work, step_s)
-    return {
-        "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
-        "n_chips": n, "step_s": step_s, "watts": watts,
-        "energy_j": watts * step_s, "bottleneck": rt.bottleneck,
-        "compute_s": rt.compute_s, "memory_s": rt.memory_s,
-        "collective_s": rt.collective_s,
-    }
-
-
-def samples_per_step(rec: dict) -> float:
-    """One 'sample' = one sequence (train/prefill) or one token (decode)."""
-    from repro.configs import SHAPES
-
-    shape = SHAPES[rec["shape"]]
-    return float(shape.global_batch)
 
 
 def csv_row(name: str, us_per_call: float, derived: str) -> str:

@@ -1,13 +1,9 @@
-"""Fig. 6: energy per inference across workloads (mJ -> hundreds of J).
-
-Per assigned architecture: J/sample for the offline prefill cell and
-J/token for decode, plus the tiny workload — reproducing the paper's
-5-orders-of-magnitude span between tiny CV and datacenter LLMs."""
+"""Fig. 6: energy per inference of the tiny workload (the mJ end of the
+paper's span)."""
 from __future__ import annotations
 
-from benchmarks.common import (cell_energy, csv_row, load_cell,
-                               samples_per_step)
-from repro.configs import ASSIGNED_ARCHS, get_config
+from benchmarks.common import csv_row
+from repro.configs import get_config
 from repro.core.power_model import TinyPowerModel
 from repro.models import tiny as tiny_mod
 
@@ -19,22 +15,6 @@ def run() -> list[dict]:
     e = tm.inference_energy(tiny_mod.macs(cfg), tiny_mod.sram_bytes(cfg))
     rows.append({"workload": "tiny-kws", "kind": "tiny-inference",
                  "j_per_sample": e, "n_chips": 1})
-    for arch in ASSIGNED_ARCHS:
-        rec = load_cell(arch, "prefill_32k", "pod")
-        if rec:
-            ce = cell_energy(rec)
-            rows.append({"workload": arch, "kind": "prefill(32k)/sample",
-                         "j_per_sample": ce["energy_j"]
-                         / samples_per_step(rec),
-                         "n_chips": ce["n_chips"]})
-        rec = load_cell(arch, "decode_32k", "pod") or \
-            load_cell(arch, "long_500k", "pod")
-        if rec:
-            ce = cell_energy(rec)
-            rows.append({"workload": arch, "kind": "decode/token",
-                         "j_per_sample": ce["energy_j"]
-                         / samples_per_step(rec),
-                         "n_chips": ce["n_chips"]})
     return rows
 
 

@@ -1,11 +1,10 @@
-"""Fig. 2: power consumption range across MLPerf categories (µW -> MW).
+"""Fig. 2: power consumption range across MLPerf categories.
 
-Reproduces the paper's headline span: tiny systems at µW average power
-(duty-cycled mW peaks), edge at watts, datacenter inference at kW, and
-training pods at hundreds of kW."""
+Reproduces the low end of the paper's headline span: tiny systems at µW
+average power (duty-cycled mW peaks) and edge at watts."""
 from __future__ import annotations
 
-from benchmarks.common import cell_energy, csv_row, load_cell
+from benchmarks.common import csv_row
 from repro.configs import get_config
 from repro.core.power_model import (StepWork, SystemPowerModel,
                                     TinyPowerModel)
@@ -33,26 +32,6 @@ def run() -> list[dict]:
     w = StepWork(flops=2.0 * n * 197, hbm_bytes=2.0 * n)   # 1 img batch
     rows.append({"category": "edge (ViT-S inference)",
                  "watts": edge.system_watts(w), "note": "single SoC"})
-    # --- datacenter inference (one pod row of 16 chips serving)
-    rec = load_cell("yi-9b", "decode_32k", "pod")
-    if rec:
-        ce = cell_energy(rec)
-        rows.append({"category": "datacenter inference (256 chips)",
-                     "watts": ce["watts"], "note": rec["arch"]})
-    # --- datacenter training single pod + multipod
-    for mesh, label in (("pod", "training pod (256 chips)"),
-                        ("multipod", "training 2 pods (512 chips)")):
-        rec = load_cell("deepseek-v3-671b", "train_4k", mesh) or \
-            load_cell("yi-9b", "train_4k", mesh)
-        if rec:
-            ce = cell_energy(rec)
-            rows.append({"category": label, "watts": ce["watts"],
-                         "note": rec["arch"]})
-    # --- extrapolated flagship scale (paper: ~10 MW training est.)
-    if rows and rec:
-        per_chip = rows[-1]["watts"] / 512
-        rows.append({"category": "extrapolated 32k-chip training",
-                     "watts": per_chip * 32768, "note": "paper's MW regime"})
     return rows
 
 

@@ -1,61 +1,17 @@
-"""Fig. 8: quantization-driven efficiency at fixed accuracy targets.
-
-Measures, per arch, normalized Samples/J for the bf16, fp16, fp8 and
-int8 deployments of the same inference cell — the FP8-closes-the-gap
-story of Fig. 8 — plus a REAL accuracy measurement on the tiny model
-(int8 vs fp32 logits agreement on CPU, the 99%/99.9% target premise).
+"""Fig. 8: quantization at a fixed accuracy target — a REAL accuracy
+measurement on the tiny model (int8 vs fp32 logits agreement on CPU, the
+99%/99.9% target premise).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import csv_row, load_cell
-from repro.core.power_model import StepWork, SystemPowerModel, roofline
-from repro.hw import DATACENTER_V5E
-
-ARCHS = ["yi-9b", "qwen3-1.7b", "granite-3-2b"]
-# effective precisions: (flops path, bytes scale) vs bf16 baseline
-PRECISIONS = {
-    "bf16": dict(int8=False, byte_scale=1.0, flop_scale=1.0),
-    "fp16": dict(int8=False, byte_scale=1.0, flop_scale=1.0),
-    "fp8": dict(int8=True, byte_scale=0.5, flop_scale=1.0),
-    "int8": dict(int8=True, byte_scale=0.5, flop_scale=1.0),
-}
-# which precision meets which accuracy target (paper's Fig. 8 narrative)
-TARGET_99 = "int8"
-TARGET_999_OLD = "fp16"       # pre-v3.1 submissions
-TARGET_999_NEW = "fp8"        # v3.1+ hardware
-
-
-def _eff(rec, precision: str) -> float:
-    p = PRECISIONS[precision]
-    base = StepWork(rec["flops"], rec["hbm_bytes"], rec["coll_bytes"])
-    work = StepWork(base.flops * p["flop_scale"],
-                    base.hbm_bytes * p["byte_scale"],
-                    base.ici_bytes * p["byte_scale"],
-                    flops_int8=base.flops if p["int8"] else 0.0)
-    model = SystemPowerModel(DATACENTER_V5E, rec["n_devices"])
-    rt = roofline(work, DATACENTER_V5E.chip)
-    watts = model.system_watts(work, rt.step_s)
-    return 1.0 / (watts * rt.step_s)          # samples/J up to const
+from benchmarks.common import csv_row
 
 
 def run() -> list[dict]:
-    rows = []
-    for arch in ARCHS:
-        rec = load_cell(arch, "prefill_32k", "pod")
-        if rec is None:
-            continue
-        base = _eff(rec, TARGET_99)
-        rows.append({
-            "arch": arch,
-            "eff_99_int8": 1.0,
-            "eff_999_fp16": _eff(rec, TARGET_999_OLD) / base,
-            "eff_999_fp8": _eff(rec, TARGET_999_NEW) / base,
-        })
-    rows.append(tiny_accuracy_measurement())
-    return rows
+    return [tiny_accuracy_measurement()]
 
 
 def tiny_accuracy_measurement() -> dict:
@@ -89,19 +45,10 @@ def tiny_accuracy_measurement() -> dict:
 
 
 def csv() -> list[str]:
-    out = []
-    for r in run():
-        if "eff_999_fp8" in r:
-            out.append(csv_row(
-                f"fig8_quant[{r['arch']}]", 0.0,
-                f"eff99_int8=1.0;eff999_fp16={r['eff_999_fp16']:.3f};"
-                f"eff999_fp8={r['eff_999_fp8']:.3f}"))
-        else:
-            out.append(csv_row(
-                "fig8_quant[tiny_accuracy]", 0.0,
-                f"agree={r['int8_argmax_agreement']:.4f};"
-                f"rel={r['int8_rel_err']:.4f}"))
-    return out
+    return [csv_row(
+        "fig8_quant[tiny_accuracy]", 0.0,
+        f"agree={r['int8_argmax_agreement']:.4f};"
+        f"rel={r['int8_rel_err']:.4f}") for r in run()]
 
 
 if __name__ == "__main__":

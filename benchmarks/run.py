@@ -21,24 +21,16 @@ def main(argv=None) -> None:
                     help="reduced sizes for the CI gate")
     args = ap.parse_args(argv)
 
-    from benchmarks import (accuracy_cost, efficiency_trends,
-                            energy_per_inference, fleet_sweep,
+    from benchmarks import (energy_per_inference, fleet_sweep,
                             power_breakdown, power_range, prefix_cache,
                             quantization_efficiency, resilience,
-                            roofline_table, scale_sweep, scaling_energy,
-                            serving_throughput, slo_sweep,
-                            speculative_efficiency, sw_hw_optimizations,
-                            tiny_edge_measured)
+                            scale_sweep, serving_throughput, slo_sweep,
+                            speculative_efficiency, tiny_edge_measured)
 
     modules = [
         ("fig2_power_range", power_range),
-        ("fig4_efficiency_trends", efficiency_trends),
-        ("fig5_scaling_energy", scaling_energy),
         ("fig6_energy_per_inference", energy_per_inference),
-        ("fig7_accuracy_cost", accuracy_cost),
         ("fig8_quantization", quantization_efficiency),
-        ("fig9_10_sw_hw", sw_hw_optimizations),
-        ("roofline_table", roofline_table),
         ("measured_tiny_edge", tiny_edge_measured),
         ("serving_throughput", serving_throughput),
         ("scale_sweep", scale_sweep),

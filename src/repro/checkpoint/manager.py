@@ -9,8 +9,7 @@ Restore is *elastic*: arrays are loaded host-side and ``device_put``
 with whatever sharding the new mesh dictates, so a run checkpointed on
 16x16 restarts cleanly on 4x4 (or on 1 CPU in tests).  At real scale
 the same interface would write per-shard files (Orbax/OCDBT style); the
-single-file path keeps the repo self-contained and is noted in
-DESIGN.md.
+single-file path keeps the repo self-contained.
 """
 from __future__ import annotations
 

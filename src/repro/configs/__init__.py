@@ -6,7 +6,7 @@ import importlib
 
 from repro.configs.base import (  # noqa: F401
     EncDecConfig, HybridConfig, MLAConfig, MambaConfig, MoEConfig,
-    ModelConfig, ShapeConfig, SHAPES, VLMConfig, shape_applicable,
+    ModelConfig, ShapeConfig, SHAPES, VLMConfig,
 )
 
 _ARCH_MODULES = {

@@ -89,31 +89,12 @@ class ModelConfig:
     # --- runtime knobs -------------------------------------------------
     dtype: str = "bfloat16"       # activation/param compute dtype
     remat: bool = True
-    scan_layers: bool = True
-    unroll_scans: bool = False    # calibration mode: no lax.scan anywhere
-                                  # (XLA cost_analysis counts loop bodies
-                                  # once; see launch/roofline.py)
-    # --- perf-iteration knobs (EXPERIMENTS.md §Perf) -------------------
-    causal_skip: bool = False     # triangular attention: only visit KV
-                                  # blocks <= q block (pallas kernel
-                                  # parity; jnp path in unroll mode)
-    attn_bf16_scores: bool = False  # bf16 score tensors, f32 row stats
-    cache_dus: bool = False       # decode cache update via
-                                  # dynamic_update_slice (vs one-hot)
-    prefill_fsdp: bool = False    # ZeRO-3 weight gathering at prefill
-    attn_chunk: int = 1024        # flash q-chunk size (jnp path)
-    remat_policy: str = "nothing"  # "nothing" | "dots" (save matmul outs)
-    sublayer_remat: bool = False  # hybrid: checkpoint each of the 8
-                                  # sublayers instead of the superblock
-                                  # (jamba: ~4x lower temp memory)
     use_pallas: bool = False      # set from the backend by
                                   # configs.for_platform (TPU: kernels)
     pallas_interpret: bool = False  # run the Pallas kernels in interpret
                                   # mode (CPU correctness/parity tests;
                                   # refused on a TPU)
     quant: Optional[str] = None   # None | "int8" | "fp8" weight/act quant
-    seq_shard_kv: bool = True     # sequence-shard KV cache for decode
-    subquadratic: bool = False    # eligible for long_500k
 
     @property
     def head_dim(self) -> int:
@@ -217,10 +198,3 @@ SHAPES: dict[str, ShapeConfig] = {
     "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
     "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
 }
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> bool:
-    """long_500k needs sub-quadratic attention (see DESIGN.md §4)."""
-    if shape.name == "long_500k":
-        return cfg.subquadratic
-    return True

@@ -16,5 +16,4 @@ CONFIG = ModelConfig(
     d_ff=1536,
     vocab_size=1000,          # classifier head
     vlm=VLMConfig(n_patches=196),
-    scan_layers=True,
 )

@@ -21,5 +21,4 @@ CONFIG = ModelConfig(
                   d_ff_dense=14336),
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2),
     hybrid=HybridConfig(attn_period=8, attn_offset=4),
-    subquadratic=True,
 )

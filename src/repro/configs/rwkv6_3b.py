@@ -15,5 +15,4 @@ CONFIG = ModelConfig(
     d_head=64,
     d_ff=8960,
     vocab_size=65536,
-    subquadratic=True,
 )

@@ -20,5 +20,4 @@ CONFIG = ModelConfig(
     vocab_size=12,            # 12 keyword classes
     dtype="float32",
     remat=False,
-    scan_layers=False,
 )

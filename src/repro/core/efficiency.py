@@ -1,8 +1,6 @@
-"""Energy-efficiency metrics + version-over-version trend analysis.
-
-The §V analyses: normalized Samples/Joule trends (Fig. 4), software- vs
-hardware-isolated improvement attribution (Figs. 9-10), accuracy-target
-efficiency cost (Fig. 7).
+"""Energy-efficiency metrics: the ``Submission`` record a ``PowerRun``
+produces (Samples/Joule, per-domain efficiency) and the Server-scenario
+capacity figures (QPS at the tail SLO, and per joule).
 """
 from __future__ import annotations
 
@@ -80,77 +78,3 @@ def qps_at_slo_per_joule(qps_at_slo: float, avg_watts: float) -> float:
     if avg_watts <= 0:
         return 0.0
     return qps_at_slo / avg_watts
-
-
-def normalized_trend(subs: list[Submission]) -> dict[str, list]:
-    """Per-workload Samples/J normalized to the first version (Fig. 4)."""
-    by_wl: dict[str, list[Submission]] = {}
-    for s in subs:
-        by_wl.setdefault(s.workload, []).append(s)
-    out = {}
-    for wl, ss in by_wl.items():
-        ss = sorted(ss, key=lambda s: s.version)
-        base = ss[0].samples_per_joule
-        out[wl] = [(s.version, s.samples_per_joule / base) for s in ss]
-    return out
-
-
-def software_isolated_deltas(subs: list[Submission]) -> list[dict]:
-    """Identical hardware, consecutive versions -> efficiency change
-    distribution (Fig. 9)."""
-    out = []
-    by_key: dict[tuple, list[Submission]] = {}
-    for s in subs:
-        by_key.setdefault((s.workload, s.system_id), []).append(s)
-    for (wl, sysid), ss in by_key.items():
-        ss = sorted(ss, key=lambda s: s.version)
-        for a, b in zip(ss, ss[1:]):
-            out.append({
-                "workload": wl, "system": sysid,
-                "from": a.version, "to": b.version,
-                "delta_pct": 100.0 * (b.samples_per_joule
-                                      / a.samples_per_joule - 1.0),
-                "perf_ratio": b.samples_per_second / a.samples_per_second,
-                "power_ratio": b.avg_watts / a.avg_watts,
-            })
-    return out
-
-
-def hardware_isolated_deltas(subs: list[Submission]) -> list[dict]:
-    """Constant software stack, successive hardware (Fig. 10b)."""
-    out = []
-    by_key: dict[tuple, list[Submission]] = {}
-    for s in subs:
-        by_key.setdefault((s.workload, s.software_id), []).append(s)
-    for (wl, swid), ss in by_key.items():
-        ss = sorted(ss, key=lambda s: s.version)
-        for a, b in zip(ss, ss[1:]):
-            if a.system_id == b.system_id:
-                continue
-            out.append({
-                "workload": wl, "software": swid,
-                "hw_from": a.system_id, "hw_to": b.system_id,
-                "eff_ratio": b.samples_per_joule / a.samples_per_joule,
-                "perf_ratio": b.samples_per_second / a.samples_per_second,
-                "power_ratio": b.avg_watts / a.avg_watts,
-            })
-    return out
-
-
-def accuracy_cost(low: Submission, high: Submission) -> float:
-    """% change in Samples/J when moving to the higher accuracy target
-    (Fig. 7; negative = efficiency lost)."""
-    return 100.0 * (high.samples_per_joule / low.samples_per_joule - 1.0)
-
-
-def summary_stats(deltas: list[dict], key: str = "delta_pct") -> dict:
-    xs = np.asarray([d[key] for d in deltas], dtype=np.float64)
-    if len(xs) == 0:
-        return {"n": 0}
-    return {
-        "n": len(xs),
-        "mean": float(np.mean(xs)),
-        "median": float(np.median(xs)),
-        "frac_positive": float(np.mean(xs > 0)),
-        "frac_gt_50pct": float(np.mean(xs > 50)),
-    }

@@ -1,7 +1,7 @@
 """Analytical power model: roofline quantities -> watts over time.
 
-This is the "sensor" behind the virtual analyzers and node telemetry
-(DESIGN.md §2).  Given a workload's per-step compute/memory/collective
+This is the "sensor" behind the virtual analyzers and node telemetry.
+Given a workload's per-step compute/memory/collective
 work (from ``compiled.cost_analysis()`` + HLO collective parsing, or
 from analytic model FLOPs), it produces a full-system power trace:
 

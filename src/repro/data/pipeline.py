@@ -75,7 +75,8 @@ def pack_documents(docs: list[np.ndarray], seq_len: int, eos: int,
 
 def batch_for_shape(cfg, shape, *, step: int = 0, seed: int = 0) -> dict:
     """A concrete (allocated) batch for an (arch, shape) cell — used by
-    CPU-scale examples and tests; the dry-run uses input_specs instead."""
+    CPU-scale examples and tests; ``launch.specs.input_specs`` gives the
+    shape-only stand-ins."""
     gen = SyntheticTokens(cfg.vocab_size, shape.seq_len, shape.global_batch,
                           seed=seed)
     batch = gen.batch(step)

@@ -1,7 +1,7 @@
 """Hardware models: chip specs and energy coefficients.
 
-All power/energy figures are documented engineering estimates (see
-DESIGN.md §2). They feed the analytical power model in ``repro.core``;
+All power/energy figures are documented engineering estimates. They
+feed the analytical power model in ``repro.core``;
 on a real cluster the model is replaced by genuine telemetry and these
 constants are only used for roofline analysis.
 """
@@ -23,7 +23,7 @@ class ChipSpec:
     ici_links: int              # links per chip (torus degree)
     idle_watts: float           # static power, chip powered but idle
     peak_watts: float           # chip power at full utilization
-    # Dynamic energy coefficients (derived; see DESIGN.md).
+    # Dynamic energy coefficients (derived).
     e_flop_bf16: float          # J per bf16 FLOP at the compute units
     e_flop_int8: float          # J per int8 OP
     e_hbm_byte: float           # J per HBM byte moved
@@ -60,41 +60,6 @@ TPU_V5E = ChipSpec(
     e_flop_int8=0.37e-12,
     e_hbm_byte=31e-12,
     e_ici_byte=40e-12,
-)
-
-# Previous / next generation chips, used only by the Fig. 10 style
-# "hardware-isolated optimization" benchmark (constant software stack,
-# successive hardware versions).
-TPU_V4 = ChipSpec(
-    name="tpu-v4",
-    peak_flops_bf16=275e12,
-    peak_flops_int8=275e12,   # no native int8 speedup
-    hbm_bandwidth=1228e9,
-    hbm_capacity=32 * 2**30,
-    ici_bandwidth=50e9,
-    ici_links=6,
-    idle_watts=90.0,
-    peak_watts=280.0,
-    e_flop_bf16=0.69e-12,
-    e_flop_int8=0.69e-12,
-    e_hbm_byte=34e-12,
-    e_ici_byte=45e-12,
-)
-
-TPU_V5P = ChipSpec(
-    name="tpu-v5p",
-    peak_flops_bf16=459e12,
-    peak_flops_int8=918e12,
-    hbm_bandwidth=2765e9,
-    hbm_capacity=95 * 2**30,
-    ici_bandwidth=100e9,
-    ici_links=6,
-    idle_watts=120.0,
-    peak_watts=350.0,
-    e_flop_bf16=0.50e-12,
-    e_flop_int8=0.25e-12,
-    e_hbm_byte=25e-12,
-    e_ici_byte=35e-12,
 )
 
 # Edge-class SoC (tens of watts): think Orin/edge-TPU class device.
@@ -178,19 +143,9 @@ class SystemSpec:
 
 
 DATACENTER_V5E = SystemSpec(chip=TPU_V5E)
-DATACENTER_V4 = SystemSpec(chip=TPU_V4)
-DATACENTER_V5P = SystemSpec(chip=TPU_V5P, chips_per_host=4)
 EDGE_SYSTEM = SystemSpec(chip=EDGE_SOC, chips_per_host=1,
                          host_idle_watts=5.0, host_active_watts=8.0,
                          switch_watts=0.0, psu_efficiency=0.90)
-
-CHIPS = {c.name: c for c in (TPU_V5E, TPU_V4, TPU_V5P, EDGE_SOC)}
-SYSTEMS = {
-    "datacenter-v5e": DATACENTER_V5E,
-    "datacenter-v4": DATACENTER_V4,
-    "datacenter-v5p": DATACENTER_V5P,
-    "edge": EDGE_SYSTEM,
-}
 
 # The modeled system behind each accelerator JAX can report, keyed by
 # ``jax.Device.device_kind``.  Watts on a chip are priced from this

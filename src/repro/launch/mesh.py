@@ -1,8 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as functions (never module-level constants) so importing this
-module never touches jax device state — required for the dry-run, which
-must set XLA_FLAGS before the first jax initialization.
+module never touches jax device state: a caller that wants virtual host
+devices sets XLA_FLAGS before the first jax initialization.
 """
 from __future__ import annotations
 
@@ -10,16 +10,8 @@ import jax
 from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """Single pod: 16x16 (data, model) = 256 chips.
-    Multi-pod: 2x16x16 (pod, data, model) = 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (scaling studies, tests, pipeline stages)."""
+    """Arbitrary mesh (tests, pipeline stages)."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -1,6 +1,6 @@
 """Roofline extraction from compiled XLA artifacts.
 
-Sources (per DESIGN.md / assignment):
+Sources:
 - ``compiled.cost_analysis()``: per-device HLO FLOPs + bytes accessed.
 - ``compiled.as_text()``: post-SPMD per-device HLO; collective bytes are
   the summed operand sizes of every all-gather / all-reduce /
@@ -129,18 +129,10 @@ class CellReport:
     step_s: float = 0.0
     # analytic HBM floor (weights/cache must stream at least once):
     # HLO 'bytes accessed' is pre-fusion and thus an upper bound; the
-    # floor bounds the truth from below (see EXPERIMENTS.md §Roofline).
+    # floor bounds the truth from below.
     min_hbm_bytes: float = 0.0
     memory_floor_s: float = 0.0
     notes: str = ""
-
-    def to_json(self) -> dict:
-        d = dataclasses.asdict(self)
-        return d
-
-    @staticmethod
-    def from_json(d: dict) -> "CellReport":
-        return CellReport(**d)
 
 
 def model_flops_for(cfg, shape) -> float:
@@ -233,41 +225,3 @@ def analytic_min_bytes(cfg, shape, n_devices: int) -> float:
     else:
         cache = cfg.n_layers * shape.seq_len * kvh * dh * 2 * 2
     return p_bytes + cache * shape.global_batch / n_devices
-
-
-def apply_calibration(report: CellReport, cal, *,
-                      chip: ChipSpec = TPU_V5E) -> CellReport:
-    """Replace scan-undercounted raw HLO costs with calibrated totals."""
-    r = dataclasses.replace(
-        report,
-        flops=cal.flops, hbm_bytes=cal.hbm_bytes, coll_bytes=cal.coll_bytes,
-        coll_breakdown={**cal.coll_breakdown,
-                        "raw_counts": report.coll_breakdown.get("counts")},
-        compute_s=cal.flops / chip.peak_flops_bf16,
-        memory_s=cal.hbm_bytes / chip.hbm_bandwidth,
-        collective_s=cal.coll_bytes / chip.ici_bandwidth,
-    )
-    terms = {"compute": r.compute_s, "memory": r.memory_s,
-             "collective": r.collective_s}
-    return dataclasses.replace(
-        r, bottleneck=max(terms, key=terms.get),
-        step_s=max(terms.values()),
-        model_flops_ratio=r.model_flops / max(r.flops * r.n_devices, 1.0),
-        notes=(report.notes + " calibrated").strip())
-
-
-def render_table(reports: list[CellReport]) -> str:
-    hdr = (f"{'arch':<20} {'shape':<12} {'mesh':<10} {'flops/dev':>10} "
-           f"{'bytes/dev':>10} {'coll/dev':>10} {'t_comp':>9} {'t_mem':>9} "
-           f"{'t_coll':>9} {'bneck':>10} {'MF-ratio':>8} {'GB/dev':>7} "
-           f"{'fits':>5}")
-    lines = [hdr, "-" * len(hdr)]
-    for r in reports:
-        live_gb = (r.arg_bytes + r.temp_bytes) / 2**30
-        lines.append(
-            f"{r.arch:<20} {r.shape:<12} {r.mesh:<10} {r.flops:>10.3e} "
-            f"{r.hbm_bytes:>10.3e} {r.coll_bytes:>10.3e} "
-            f"{r.compute_s:>9.4f} {r.memory_s:>9.4f} {r.collective_s:>9.4f} "
-            f"{r.bottleneck:>10} {r.model_flops_ratio:>8.3f} "
-            f"{live_gb:>7.2f} {str(r.fits_hbm):>5}")
-    return "\n".join(lines)

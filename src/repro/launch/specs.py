@@ -4,7 +4,7 @@
 for every model input — shardable, no device allocation — plus the
 matching PartitionSpec trees.  ``build_cell`` assembles the jit-able
 step function and its abstract arguments for one cell, ready for
-``.lower().compile()`` in the dry-run or for real execution.
+``.lower().compile()`` against abstract arguments or for real execution.
 """
 from __future__ import annotations
 

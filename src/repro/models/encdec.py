@@ -101,13 +101,7 @@ class EncDecLM:
             return xx, None
 
         body = self._maybe_remat(body)
-        if cfg.unroll_scans or not cfg.scan_layers:
-            n = jax.tree.leaves(params["enc_blocks"])[0].shape[0]
-            for i in range(n):
-                x, _ = body(x, jax.tree.map(lambda a: a[i],
-                                            params["enc_blocks"]))
-        else:
-            x, _ = jax.lax.scan(body, x, params["enc_blocks"])
+        x, _ = jax.lax.scan(body, x, params["enc_blocks"])
         return L.rmsnorm(x, params["enc_norm"], cfg.norm_eps)
 
     def _dec_stack(self, params, x, mode, cache, pos, xkv):
@@ -139,21 +133,7 @@ class EncDecLM:
             return xx, nc
 
         body = self._maybe_remat(body) if mode == "train" else body
-        if cfg.unroll_scans or not cfg.scan_layers:
-            n = jax.tree.leaves(params["dec_blocks"])[0].shape[0]
-            ncs = []
-            for i in range(n):
-                def sl(a, i=i):
-                    return a[i]
-                x, nc_i = body(x, (jax.tree.map(sl, params["dec_blocks"]),
-                                   jax.tree.map(sl, cache),
-                                   jax.tree.map(sl, xkv)))
-                ncs.append(nc_i)
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
-            return x, new_cache
-        x, new_cache = jax.lax.scan(body, x,
-                                    (params["dec_blocks"], cache, xkv))
-        return x, new_cache
+        return jax.lax.scan(body, x, (params["dec_blocks"], cache, xkv))
 
     def _cross_kv_all(self, params, enc_out):
         cfg = self.cfg
@@ -161,12 +141,6 @@ class EncDecLM:
         def body(_, bp):
             return None, cross_kv(enc_out, bp["xattn"], cfg)
 
-        if cfg.unroll_scans or not cfg.scan_layers:
-            n = jax.tree.leaves(params["dec_blocks"])[0].shape[0]
-            outs = [body(None, jax.tree.map(lambda a: a[i],
-                                            params["dec_blocks"]))[1]
-                    for i in range(n)]
-            return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         _, xkv = jax.lax.scan(body, None, params["dec_blocks"])
         return xkv            # (k, v) each (L, B, enc_len, KVH, dh)
 

@@ -20,6 +20,7 @@ from repro.parallel.sharding import (current_rules, expert_axes, shard,
 
 MASK_VALUE = -1e30
 VOCAB_PAD = 2048
+ATTN_CHUNK = 1024      # q-chunk of the jnp flash attention
 
 
 def pad_vocab(v: int) -> int:
@@ -75,31 +76,16 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
 # ----------------------------------------------------------------------
 # Attention cores
 # ----------------------------------------------------------------------
-def _attend_block(q, k, v, bias, scale, bf16_scores=False):
+def _attend_block(q, k, v, bias, scale):
     """One (q-chunk x full-KV) attention with f32 softmax.
 
     q: (B, Cq, H, D); k, v: (B, S, KVH, D) with H % KVH == 0.
     bias: broadcastable to (B, H, Cq, S) additive mask.
-    ``bf16_scores``: keep the O(Cq*S) tensors in bf16 (f32 row stats) —
-    halves score-tensor HBM traffic at <1e-2 relative error.
     """
     b, cq, h, d = q.shape
     kvh = k.shape[2]
     g = h // kvh
     qg = q.reshape(b, cq, kvh, g, d)
-    if bf16_scores:
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.bfloat16),
-                       k.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32) * scale
-        s = (s.reshape(b, h, cq, k.shape[1]) + bias)
-        m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
-        e = jnp.exp((s - m)).astype(jnp.bfloat16)
-        denom = jnp.sum(e.astype(jnp.float32), axis=-1, keepdims=True)
-        p = (e / denom.astype(jnp.bfloat16)).reshape(
-            b, kvh, g, cq, k.shape[1])
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-        return o.reshape(b, cq, h, d).astype(q.dtype)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     s = s.reshape(b, h, cq, k.shape[1]) + bias
@@ -110,22 +96,20 @@ def _attend_block(q, k, v, bias, scale, bf16_scores=False):
 
 
 def flash_attention_jnp(q, k, v, *, causal: bool, q_offset=0,
-                        kv_len: Optional[jax.Array] = None,
-                        chunk: int = 1024, unroll: bool = False,
-                        triangular: bool = False,
-                        bf16_scores: bool = False) -> jax.Array:
-    """Chunked attention: scan over q chunks, full KV per chunk.
+                        kv_len: Optional[jax.Array] = None) -> jax.Array:
+    """Chunked attention: scan over q chunks of ``ATTN_CHUNK``, full KV
+    per chunk.
 
     Memory is O(Cq * S) instead of O(S^2).  ``q_offset`` is the absolute
     position of q[0] (for prefill continuation); ``kv_len`` masks a
-    partially-filled KV cache.  ``unroll``: python loop instead of scan
-    (cost-analysis calibration; XLA counts loop bodies once).
+    partially-filled KV cache.
     """
     b, sq, h, d = q.shape
     skv = k.shape[1]
     scale = 1.0 / math.sqrt(d)
     kv_pos = jnp.arange(skv)
     valid = jnp.ones((skv,), bool) if kv_len is None else kv_pos < kv_len
+    chunk = ATTN_CHUNK
 
     def bias_for(q_pos):
         m = valid[None, :]
@@ -134,8 +118,7 @@ def flash_attention_jnp(q, k, v, *, causal: bool, q_offset=0,
         return jnp.where(m, 0.0, MASK_VALUE)[None, None]   # (1,1,Cq,S)
 
     if sq <= chunk:
-        return _attend_block(q, k, v, bias_for(jnp.arange(sq)), scale,
-                             bf16_scores)
+        return _attend_block(q, k, v, bias_for(jnp.arange(sq)), scale)
 
     pad_q = (-sq) % chunk
     if pad_q:                      # e.g. whisper's 1500-frame encoder
@@ -144,31 +127,12 @@ def flash_attention_jnp(q, k, v, *, causal: bool, q_offset=0,
     n = sq_p // chunk
     qc = q.reshape(b, n, chunk, h, d).transpose(1, 0, 2, 3, 4)
 
-    if unroll:
-        outs = []
-        for i in range(n):
-            pos = i * chunk + jnp.arange(chunk)
-            if triangular and causal and q_offset == 0 and kv_len is None:
-                # only visit KV blocks at or below the diagonal — the
-                # same block-skipping the Pallas kernel does with pl.when
-                hi = (i + 1) * chunk
-                bias = jnp.where(
-                    jnp.arange(hi)[None, :] <= pos[:, None], 0.0,
-                    MASK_VALUE)[None, None]
-                outs.append(_attend_block(qc[i], k[:, :hi], v[:, :hi],
-                                          bias, scale, bf16_scores))
-            else:
-                outs.append(_attend_block(qc[i], k, v, bias_for(pos),
-                                          scale, bf16_scores))
-        oc = jnp.stack(outs)
-    else:
-        def body(_, qi_i):
-            qi, i = qi_i
-            pos = i * chunk + jnp.arange(chunk)
-            return None, _attend_block(qi, k, v, bias_for(pos), scale,
-                                       bf16_scores)
+    def body(_, qi_i):
+        qi, i = qi_i
+        pos = i * chunk + jnp.arange(chunk)
+        return None, _attend_block(qi, k, v, bias_for(pos), scale)
 
-        _, oc = jax.lax.scan(body, None, (qc, jnp.arange(n)))
+    _, oc = jax.lax.scan(body, None, (qc, jnp.arange(n)))
     out = oc.transpose(1, 0, 2, 3, 4).reshape(b, sq_p, h, d)
     return out[:, :sq] if pad_q else out
 
@@ -228,22 +192,16 @@ def verify_attention_jnp(q, k_cache, v_cache, pos) -> jax.Array:
     return o.reshape(b, t, h, d).astype(q.dtype)
 
 
-def cache_update_window(cache: jax.Array, new: jax.Array, pos,
-                        dus: bool = False) -> jax.Array:
+def cache_update_window(cache: jax.Array, new: jax.Array,
+                        pos) -> jax.Array:
     """Insert ``new`` (B, T, KVH, D) at rows ``[pos, pos + T)`` of the
     cache — the speculative verify window write.  ``pos`` is a scalar
     or a per-slot (B,) vector; every slot writes its own contiguous
-    window.  Same two strategies as ``cache_update``: one-hot masked
-    update (shards cleanly) or per-row ``dynamic_update_slice`` (one
-    small contiguous write)."""
+    window, by the same one-hot masked update as ``cache_update``."""
     t = new.shape[1]
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         pos = jnp.full((cache.shape[0],), pos, jnp.int32)
-    if dus:
-        return jax.vmap(
-            lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(
-                c, n.astype(c.dtype), p, axis=0))(cache, new, pos)
     rows = pos[:, None] + jnp.arange(t)[None, :]              # (B, T)
     oh = (jnp.arange(cache.shape[1])[None, None, :]
           == rows[:, :, None]).astype(cache.dtype)            # (B, T, S)
@@ -252,31 +210,21 @@ def cache_update_window(cache: jax.Array, new: jax.Array, pos,
             + jnp.einsum("bts,btkd->bskd", oh, new.astype(cache.dtype)))
 
 
-def cache_update(cache: jax.Array, new: jax.Array, pos,
-                 dus: bool = False) -> jax.Array:
+def cache_update(cache: jax.Array, new: jax.Array, pos) -> jax.Array:
     """Insert ``new`` (B, 1, KVH, D) at index ``pos`` of a seq-sharded cache.
 
     ``pos`` is a scalar (whole batch at one depth) or a per-slot (B,)
     vector (continuous batching: every row writes its own depth).
 
-    Default: one-hot masked update — elementwise, shards cleanly, but
-    costs 2 reads + 1 write of the whole cache.  ``dus``: in-place
-    dynamic_update_slice (1 tiny write); SPMD handles the sharded seq
-    dim with an owner-select (perf iteration, EXPERIMENTS.md §Perf).
+    One-hot masked update: elementwise, shards cleanly, but costs
+    2 reads + 1 write of the whole cache.
     """
     pos = jnp.asarray(pos)
     if pos.ndim == 1:
-        if dus:
-            return jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice_in_dim(
-                    c, n.astype(c.dtype), p, axis=0))(cache, new, pos)
         oh = (jnp.arange(cache.shape[1])[None, :]
               == pos[:, None]).astype(cache.dtype)       # (B, S)
         oh = oh[:, :, None, None]
         return cache * (1 - oh) + new.astype(cache.dtype) * oh
-    if dus:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache, new.astype(cache.dtype), pos, axis=1)
     oh = (jnp.arange(cache.shape[1]) == pos).astype(cache.dtype)
     oh = oh[None, :, None, None]
     return cache * (1 - oh) + new.astype(cache.dtype) * oh
@@ -340,11 +288,7 @@ def gqa_attention(x, p, cfg, *, causal=True, positions=None, use_rope=True):
     q = shard(q, "batch", None, "heads", None)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
-    o = flash_attention_jnp(q, k, v, causal=causal,
-                            unroll=cfg.unroll_scans,
-                            triangular=cfg.causal_skip,
-                            bf16_scores=cfg.attn_bf16_scores,
-                            chunk=cfg.attn_chunk)
+    o = flash_attention_jnp(q, k, v, causal=causal)
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     o = tp_psum(o @ p["wo"])
     return shard(o, "batch", "seq_sp", "embed")
@@ -361,10 +305,7 @@ def gqa_prefill(x, p, cfg, positions=None):
     q = shard(q, "batch", None, "heads", None)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
-    o = flash_attention_jnp(q, k, v, causal=True, unroll=cfg.unroll_scans,
-                            triangular=cfg.causal_skip,
-                            bf16_scores=cfg.attn_bf16_scores,
-                            chunk=cfg.attn_chunk)
+    o = flash_attention_jnp(q, k, v, causal=True)
     o = tp_psum(o.reshape(b, s, -1) @ p["wo"])
     return shard(o, "batch", "seq_sp", "embed"), (k, v)
 
@@ -384,8 +325,8 @@ def gqa_decode(x, p, cfg, cache, pos):
     poss = pos[:, None] if pos.ndim == 1 else jnp.full((1,), pos)
     q = rope(q, poss, cfg.rope_theta)
     k = rope(k, poss, cfg.rope_theta)
-    k_cache = cache_update(cache["k"], k, pos, dus=cfg.cache_dus)
-    v_cache = cache_update(cache["v"], v, pos, dus=cfg.cache_dus)
+    k_cache = cache_update(cache["k"], k, pos)
+    v_cache = cache_update(cache["v"], v, pos)
     k_cache = shard(k_cache, "batch", "kv_seq", None, None)
     v_cache = shard(v_cache, "batch", "kv_seq", None, None)
     if cfg.use_pallas:
@@ -419,8 +360,8 @@ def gqa_verify(x, p, cfg, cache, pos):
     positions = pos[:, None] + jnp.arange(t)[None, :]         # (B, T)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    k_cache = cache_update_window(cache["k"], k, pos, dus=cfg.cache_dus)
-    v_cache = cache_update_window(cache["v"], v, pos, dus=cfg.cache_dus)
+    k_cache = cache_update_window(cache["k"], k, pos)
+    v_cache = cache_update_window(cache["v"], v, pos)
     k_cache = shard(k_cache, "batch", "kv_seq", None, None)
     v_cache = shard(v_cache, "batch", "kv_seq", None, None)
     if cfg.use_pallas:
@@ -636,17 +577,12 @@ def _mla_ol_chunked(q_abs, q_rope, c_kv, k_rope, cfg, q_chunk=1024):
     qa = q_abs.reshape(b, n, q_chunk, *q_abs.shape[2:]).transpose(1, 0, 2, 3, 4)
     qr = q_rope.reshape(b, n, q_chunk, *q_rope.shape[2:]).transpose(1, 0, 2, 3, 4)
 
-    if cfg.unroll_scans:
-        oc = jnp.stack([
-            _mla_attend_chunk(qa[i], qr[i], c_kv, k_rope, cfg, i * q_chunk)
-            for i in range(n)])
-    else:
-        def body(_, args):
-            qa_i, qr_i, i = args
-            return None, _mla_attend_chunk(qa_i, qr_i, c_kv, k_rope, cfg,
-                                           i * q_chunk)
+    def body(_, args):
+        qa_i, qr_i, i = args
+        return None, _mla_attend_chunk(qa_i, qr_i, c_kv, k_rope, cfg,
+                                       i * q_chunk)
 
-        _, oc = jax.lax.scan(body, None, (qa, qr, jnp.arange(n)))
+    _, oc = jax.lax.scan(body, None, (qa, qr, jnp.arange(n)))
     return oc.transpose(1, 0, 2, 3, 4).reshape(b, s, cfg.n_heads,
                                                cfg.mla.kv_lora_rank)
 
@@ -706,23 +642,10 @@ def mla_decode(x, p, cfg, cache, pos):
     ckv = cache["c_kv"]
     krp = cache["k_rope"]
     if pos.ndim == 1:
-        if cfg.cache_dus:
-            ckv = jax.vmap(
-                lambda c, n, pp: jax.lax.dynamic_update_slice_in_dim(
-                    c, n.astype(c.dtype), pp, axis=0))(ckv, c_new, pos)
-            krp = jax.vmap(
-                lambda c, n, pp: jax.lax.dynamic_update_slice_in_dim(
-                    c, n.astype(c.dtype), pp, axis=0))(krp, kr_new, pos)
-        else:
-            oh = (jnp.arange(ckv.shape[1])[None, :]
-                  == pos[:, None]).astype(ckv.dtype)      # (B, S)
-            ckv = ckv * (1 - oh[:, :, None]) + c_new * oh[:, :, None]
-            krp = krp * (1 - oh[:, :, None]) + kr_new * oh[:, :, None]
-    elif cfg.cache_dus:
-        ckv = jax.lax.dynamic_update_slice_in_dim(
-            ckv, c_new.astype(ckv.dtype), pos, axis=1)
-        krp = jax.lax.dynamic_update_slice_in_dim(
-            krp, kr_new.astype(krp.dtype), pos, axis=1)
+        oh = (jnp.arange(ckv.shape[1])[None, :]
+              == pos[:, None]).astype(ckv.dtype)          # (B, S)
+        ckv = ckv * (1 - oh[:, :, None]) + c_new * oh[:, :, None]
+        krp = krp * (1 - oh[:, :, None]) + kr_new * oh[:, :, None]
     else:
         oh = (jnp.arange(ckv.shape[1]) == pos).astype(ckv.dtype)
         ckv = ckv * (1 - oh[None, :, None]) + c_new * oh[None, :, None]

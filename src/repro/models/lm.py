@@ -3,7 +3,7 @@
 One ``LM`` class covers all assigned decoder-only architectures through
 a per-family block builder.  Layers are scanned (``lax.scan``) with
 optional remat; parameters come from a single ``ParamDef`` tree (see
-``repro.models.param``) so real init, dry-run ShapeDtypeStructs and
+``repro.models.param``) so real init, shape-only ShapeDtypeStructs and
 PartitionSpecs never drift.
 
 Public API (uniform across families; whisper has its own class):
@@ -265,10 +265,8 @@ class LM:
     # ------------------------------------------------------------------
     def _maybe_remat(self, fn):
         if self.cfg.remat:
-            policy = (jax.checkpoint_policies.nothing_saveable
-                      if self.cfg.remat_policy == "nothing" else
-                      jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-            return jax.checkpoint(fn, policy=policy)
+            return jax.checkpoint(
+                fn, policy=jax.checkpoint_policies.nothing_saveable)
         return fn
 
     def _run_stack(self, params, x, mode, cache, pos, pages=None):
@@ -287,17 +285,6 @@ class LM:
                 return xx, (nc, aux)
 
             body = self._maybe_remat(body) if mode == "train" else body
-            if not cfg.scan_layers or cfg.unroll_scans:
-                n = jax.tree.leaves(stacked)[0].shape[0]
-                ncs, aux_l = [], []
-                for i in range(n):
-                    bp_i = jax.tree.map(lambda a: a[i], stacked)
-                    c_i = jax.tree.map(lambda a: a[i], cache_g)
-                    x, (nc_i, aux_i) = body(x, (bp_i, c_i))
-                    ncs.append(nc_i)
-                    aux_l.append(aux_i)
-                nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
-                return x, nc, jnp.mean(jnp.stack(aux_l))
             x, (nc, aux) = jax.lax.scan(body, x, (stacked, cache_g))
             return x, nc, jnp.mean(aux)
 
@@ -312,31 +299,12 @@ class LM:
                 nc = {}
                 for j in range(period):
                     key = f"l{j}_{kinds[j]}"
-
-                    def sub(x_, bp_, c_, k_=kinds[j]):
-                        return self._apply_block(x_, bp_, k_, mode, c_,
-                                                 pos)
-
-                    if mode == "train" and cfg.sublayer_remat:
-                        sub = self._maybe_remat(sub)
-                    xx, nc_j, aux = sub(xx, bp[key], c[key])
-                    nc[key] = nc_j
+                    xx, nc[key], aux = self._apply_block(
+                        xx, bp[key], kinds[j], mode, c[key], pos)
                     aux_sum += aux
                 return xx, (nc, aux_sum / period)
 
-            if mode == "train" and not cfg.sublayer_remat:
-                body = self._maybe_remat(body)
-            if not cfg.scan_layers or cfg.unroll_scans:
-                n = jax.tree.leaves(params["superblocks"])[0].shape[0]
-                x_c, ncs, aux_l = x, [], []
-                for i in range(n):
-                    bp_i = jax.tree.map(lambda a: a[i], params["superblocks"])
-                    c_i = jax.tree.map(lambda a: a[i], cache["superblocks"])
-                    x_c, (nc_i, aux_i) = body(x_c, (bp_i, c_i))
-                    ncs.append(nc_i)
-                    aux_l.append(aux_i)
-                nc = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
-                return x_c, {"superblocks": nc}, jnp.mean(jnp.stack(aux_l))
+            body = self._maybe_remat(body) if mode == "train" else body
             x, (new_cache, aux) = jax.lax.scan(
                 body, x, (params["superblocks"], cache["superblocks"]))
             return x, {"superblocks": new_cache}, jnp.mean(aux)
@@ -359,7 +327,6 @@ class LM:
         so under a donated or loop-carried state the pools stay in one
         buffer, updated in place.  One page table serves every layer."""
         kind = self._layer_kinds()[0]
-        stacked = params["blocks"]
 
         def body(carry, xs):
             xx, c = carry
@@ -368,18 +335,9 @@ class LM:
                                            pages, layer)
             return (xx, c), aux
 
-        n = self.cfg.n_layers
-        if not self.cfg.scan_layers or self.cfg.unroll_scans:
-            carry, aux_l = (x, cache["blocks"]), []
-            for i in range(n):
-                carry, aux_i = body(
-                    carry, (jax.tree.map(lambda a: a[i], stacked), i))
-                aux_l.append(aux_i)
-            (x, nc), aux = carry, jnp.stack(aux_l)
-        else:
-            (x, nc), aux = jax.lax.scan(
-                body, (x, cache["blocks"]),
-                (stacked, jnp.arange(n, dtype=jnp.int32)))
+        (x, nc), aux = jax.lax.scan(
+            body, (x, cache["blocks"]),
+            (params["blocks"], jnp.arange(self.cfg.n_layers, dtype=jnp.int32)))
         return x, {"blocks": nc}, jnp.mean(aux)
 
     # ------------------------------------------------------------------

@@ -5,7 +5,7 @@ Sequence recurrence is computed with a two-level chunked scan: an outer
 ``lax.scan`` over chunks carrying the SSM state, an inner associative
 scan within each chunk — O(T) FLOPs, bounded memory, and no cross-device
 recurrence (Mamba layers are tensor-parallel over d_inner, NOT
-sequence-parallel; see DESIGN.md §5).
+sequence-parallel).
 """
 from __future__ import annotations
 
@@ -41,7 +41,7 @@ def mamba_defs(cfg) -> dict:
     }
 
 
-def _ssm_scan(a, b, unroll: bool = False):
+def _ssm_scan(a, b):
     """h_t = a_t * h_{t-1} + b_t over axis 1 (seq), chunked.
 
     a, b: (B, S, Din, N) float32.  Returns h for every t.
@@ -64,15 +64,7 @@ def _ssm_scan(a, b, unroll: bool = False):
         return h_all[:, -1], h_all
 
     h0 = jnp.zeros((bsz, d_in, n), jnp.float32)
-    if unroll:
-        hs = []
-        h = h0
-        for i in range(nchunk):
-            h, h_all = outer(h, (a_c[i], b_c[i]))
-            hs.append(h_all)
-        h_c = jnp.stack(hs)
-    else:
-        _, h_c = jax.lax.scan(outer, h0, (a_c, b_c))
+    _, h_c = jax.lax.scan(outer, h0, (a_c, b_c))
     return h_c.transpose(1, 0, 2, 3, 4).reshape(bsz, s, d_in, n)
 
 
@@ -114,7 +106,7 @@ def mamba_block(x, p, cfg, return_state: bool = False):
     xf = x_in.astype(jnp.float32)
     a_bar = jnp.exp(dt[..., None] * A)                         # (B,S,Din,N)
     b_bar = (dt * xf)[..., None] * B[:, :, None, :]
-    h = _ssm_scan(a_bar, b_bar, unroll=cfg.unroll_scans)
+    h = _ssm_scan(a_bar, b_bar)
     y = jnp.einsum("bsdn,bsn->bsd", h, C) + p["D"] * xf
     y = (y.astype(x.dtype)) * jax.nn.silu(z)
     out = y @ p["out_proj"]

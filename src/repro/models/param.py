@@ -2,8 +2,9 @@
 
 A model declares its parameters as a pytree of ``ParamDef`` (shape +
 logical axis names + init).  From that one tree we derive, without
-drift: real initialized params, ``ShapeDtypeStruct`` stand-ins for the
-dry-run, and ``PartitionSpec`` trees for pjit in/out shardings.
+drift: real initialized params, ``ShapeDtypeStruct`` stand-ins for
+shape-only lowering, and ``PartitionSpec`` trees for pjit in/out
+shardings.
 """
 from __future__ import annotations
 

@@ -5,12 +5,11 @@ The defining Finch feature — per-channel, per-token decay ``w_t``
 produced from the input through a low-rank projection — is implemented
 exactly.  Token-shift interpolation uses learned static mix vectors
 (RWKV-5 style) rather than the full 5-way data-dependent ddlerp; this is
-a documented simplification (DESIGN.md) that does not change the kernel
-structure.
+a simplification that does not change the kernel structure.
 
 Heads are padded from 40 to 48 (multiple of 16) so the time-mix state
 shards over the model axis; the padding is a fixed, mesh-independent
-constant (DESIGN.md §5).
+constant.
 
 The sequence recurrence uses the chunked linear-attention form (the
 same algorithm as the ``linear_scan`` Pallas kernel):
@@ -93,8 +92,8 @@ def _group_norm(x, w, n_heads, eps=1e-5):
     return (xh.reshape(b, s, -1) * w).astype(x.dtype)
 
 
-def wkv_chunked(r, k, v, logw, u, chunk=CHUNK, state0=None, return_state=False,
-                unroll=False):
+def wkv_chunked(r, k, v, logw, u, chunk=CHUNK, state0=None,
+                return_state=False):
     """Chunked RWKV-6 recurrence.
 
     r,k,v: (B, S, H, dh); logw: (B, S, H, dh) = log decay (<= 0);
@@ -103,8 +102,7 @@ def wkv_chunked(r, k, v, logw, u, chunk=CHUNK, state0=None, return_state=False,
       y_t = r_t . (S_{t-1} + diag(u) k_t v_t^T)
     """
     b, s, h, dh = r.shape
-    # seq-adaptive chunking: bound the scan trip count at 32 so the
-    # cost-calibration unroll stays compilable at 32k+ context (intra-
+    # seq-adaptive chunking: bound the scan trip count at 32 (intra-
     # chunk pairwise work stays <6% of the time-mix matmuls either way)
     chunk = min(max(chunk, s // 32), s)
     assert s % chunk == 0
@@ -145,15 +143,7 @@ def wkv_chunked(r, k, v, logw, u, chunk=CHUNK, state0=None, return_state=False,
         S = dec_end[..., None] * S + jnp.einsum("bchd,bche->bhde", k_dec, vi)
         return S, y_cross + y_intra + y_diag
 
-    if unroll:
-        ys = []
-        S = state0
-        for i in range(n):
-            S, yi = body(S, (rf[i], kf[i], vf[i], lw[i]))
-            ys.append(yi)
-        y = jnp.stack(ys)
-    else:
-        S, y = jax.lax.scan(body, state0, (rf, kf, vf, lw))
+    S, y = jax.lax.scan(body, state0, (rf, kf, vf, lw))
     y = y.transpose(1, 0, 2, 3, 4).reshape(b, s, h, dh)
     if return_state:
         return y.astype(r.dtype), S
@@ -187,7 +177,7 @@ def time_mix(x, p, cfg, state0=None, return_state=False):
     v = shard(v, "batch", None, "rwkv_heads", None)
     u = p["bonus_u"].reshape(hp, cfg.head_dim)
     out = wkv_chunked(r, k, v, logw, u, state0=state0,
-                      return_state=return_state, unroll=cfg.unroll_scans)
+                      return_state=return_state)
     y, S = out if return_state else (out, None)
     y = _group_norm(y.reshape(b, s, -1), p["ln_out"], hp)
     y = (y * jax.nn.silu(g)) @ p["w_o"]

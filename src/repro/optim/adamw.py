@@ -5,7 +5,7 @@ sharded) parameters, which under pjit is exactly ZeRO: every moment
 shard lives on the chip that owns the parameter shard.  The optional
 ``quant_moments`` mode stores m/v as int8 with per-row scales — a
 beyond-paper memory optimization that makes deepseek-v3 training states
-fit v5e HBM (see EXPERIMENTS.md §Perf).
+fit v5e HBM.
 """
 from __future__ import annotations
 

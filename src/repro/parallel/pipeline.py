@@ -12,7 +12,7 @@ executes the same tick loop; at tick t it runs its stage on microbatch
 layers are stacked (S, L/S, ...) so each stage reads its slab.
 
 This is the optional alternative to the production DP x TP(+EP) mesh
-(DESIGN.md §5) and is exercised by a real multi-device subprocess test.
+and is exercised by a real multi-device subprocess test.
 """
 from __future__ import annotations
 

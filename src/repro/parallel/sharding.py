@@ -3,8 +3,8 @@
 Models annotate tensors with *logical* axis names; ``make_rules`` maps
 them onto the physical mesh axes ``(pod, data, model)`` with per-config
 divisibility fallbacks.  Outside a sharding context every annotation is
-a no-op, so the same model code runs single-device smoke tests and the
-512-chip dry-run unchanged.
+a no-op, so the same model code runs single-device smoke tests and
+multi-device meshes unchanged.
 
 Logical axes
 ------------
@@ -193,7 +193,7 @@ def make_rules(cfg, mesh: Optional[Mesh], mode: str = "train") -> ShardingRules:
     table: dict[str, Axis] = {
         "batch": dp if dp else None,
         "seq_sp": tp_axis if mode in ("train", "prefill") else None,
-        "kv_seq": tp_axis if cfg.seq_shard_kv else None,
+        "kv_seq": tp_axis,
         "heads": fits(heads, tp_axis),
         "kv_heads": fits(kvh, tp_axis),
         "heads_flat": fits(heads, tp_axis),
@@ -202,9 +202,7 @@ def make_rules(cfg, mesh: Optional[Mesh], mode: str = "train") -> ShardingRules:
         "d_expert": None,
         "vocab": tp_axis,     # vocab is padded to a multiple of 2048
         "embed": None,
-        "fsdp": dp if (dp and (mode == "train" or
-                               (mode == "prefill" and cfg.prefill_fsdp)))
-        else None,
+        "fsdp": dp if (dp and mode == "train") else None,
         "experts": None,
         "experts_big": None,
         "d_inner": None,

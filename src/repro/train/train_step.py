@@ -2,8 +2,8 @@
 optional gradient-accumulation microbatching.
 
 The same ``make_train_step`` product is used by the real CPU training
-examples, the multi-pod dry-run (lowered against ShapeDtypeStructs) and
-the benchmarks; sharding comes from the ParamDef tree + logical rules.
+examples, the compile-only cells of ``repro.launch.specs`` (lowered
+against ShapeDtypeStructs) and the benchmarks; sharding comes from the ParamDef tree + logical rules.
 """
 from __future__ import annotations
 

@@ -1,5 +1,6 @@
 # NOTE: no XLA_FLAGS here — smoke tests and benches must see the real
-# single CPU device; only launch/dryrun.py forces 512 placeholder devices.
+# single CPU device; tests that need a mesh force host devices in a
+# subprocess.
 import os
 import sys
 

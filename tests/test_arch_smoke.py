@@ -1,6 +1,6 @@
 """Per-architecture smoke tests: REDUCED config of the same family, one
 forward/train step on CPU, asserting output shapes + no NaNs.  The FULL
-configs are exercised only via the dry-run (ShapeDtypeStruct)."""
+configs are exercised only by lowering against ShapeDtypeStructs."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -123,3 +123,4 @@ def test_tiny_model_runs():
     assert logits.shape == (4, cfg.vocab_size)
     assert jnp.isfinite(logits).all()
     assert model.macs > 0 and model.sram_bytes > 0
+

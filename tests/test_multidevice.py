@@ -1,6 +1,6 @@
 """Real multi-device tests (subprocess with forced host devices):
 SPMD train-step equivalence, pipeline-parallel correctness, MoE
-expert-parallel shard_map path, dry-run cell compilation."""
+expert-parallel shard_map path, compile-only cell lowering."""
 import os
 import subprocess
 import sys
@@ -119,7 +119,9 @@ def test_pipeline_parallel_matches_sequential():
 
 
 def test_dryrun_cell_compiles_small_mesh():
-    """The dry-run machinery end to end on an 8-device mesh."""
+    """A compile-only cell (``launch.specs.build_cell``) lowered,
+    compiled and read by ``launch.roofline.analyze`` on an 8-device
+    mesh."""
     out = run_py("""
         import jax, dataclasses
         from repro.configs import get_config, SHAPES

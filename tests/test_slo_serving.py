@@ -1,11 +1,10 @@
 """SLO-aware serving: scheduler policy units, chunked-prefill TTFT
-wins + token parity on a bimodal trace, preempt/park/resume
-bit-identity under page-pool pressure, the disaggregated prefill
-fleet's priority ordering, and the tail-SLO metric plumbing
+wins (on an injected clock) + token parity on a bimodal trace,
+preempt/park/resume bit-identity under page-pool pressure, the
+disaggregated prefill fleet's priority ordering, and the tail-SLO metric plumbing
 (ServerMetrics.tail_attainment, max_sustainable_qps)."""
 import dataclasses
 import queue
-import time
 from types import SimpleNamespace
 
 import jax
@@ -40,13 +39,6 @@ def _req(rid, n_prompt, budget, arrival_s=0.0, priority=0,
 
 def _by_rid(done):
     return {r.rid: tuple(r.output) for r in done}
-
-
-def _timed_serve(engine, n_prompt):
-    t0 = time.perf_counter()
-    engine.serve([_req(93, n_prompt, 1, seed_off=600)],
-                 honor_arrivals=False)
-    return time.perf_counter() - t0
 
 
 # --- Scheduler policy (pure host-side) -----------------------------------
@@ -88,16 +80,51 @@ def test_engine_validates_slo_knobs():
                                  scheduler=Scheduler(preemption=True))
 
 
-# --- Chunked prefill: bimodal trace, real clock --------------------------
+# --- Chunked prefill: bimodal trace, injected clock ----------------------
+
+TOKEN_S = 1e-3          # serve-clock cost of one prompt token's prefill
+DECODE_CHUNK_S = 0.01   # serve-clock cost of one decode chunk
+
+
+class TokenClock:
+    """A serve clock that moves only when the engine sleeps, prefills
+    (``TOKEN_S`` for each prompt token the call computes) or runs a
+    decode chunk (``DECODE_CHUNK_S``)."""
+
+    # the jitted body -> which positional argument holds its tokens
+    PREFILLS = {"_prefill_slot": 3, "_prefill_chunk": 2, "_extend_slot": 4}
+
+    def __init__(self, engine):
+        self.t = 0.0
+        for name, arg in self.PREFILLS.items():
+            setattr(engine, name, self._timed(
+                getattr(engine, name),
+                lambda args, i=arg: TOKEN_S * args[i].shape[1]))
+        engine._decode_chunk = self._timed(engine._decode_chunk,
+                                           lambda args: DECODE_CHUNK_S)
+
+    def _timed(self, fn, cost):
+        def run(*args):
+            self.t += cost(args)
+            return fn(*args)
+        return run
+
+    def now(self):
+        return self.t
+
+    def sleep(self, dt):
+        self.t += max(0.0, dt)
+
 
 def test_chunked_prefill_improves_short_ttft_and_keeps_tokens():
     """On a bimodal short/long trace, chunked prefill must strictly
     improve the interactive class's worst TTFT (shorts stop waiting
     out whole long prefills) while emitting bit-identical tokens at
     equal budgets.  Prompt lengths cross chunk boundaries (sub-chunk,
-    exact multiple, and non-multiple).  Arrival times are calibrated
-    to the measured warm monolithic long-prefill time so the shorts
-    land *inside* the long's prefill window on any machine speed."""
+    exact multiple, and non-multiple).  Time is a ``TokenClock``:
+    prefill costs in proportion to its tokens and a decode chunk a
+    fixed time, so the shorts land *inside* the long's prefill window
+    whatever the host's speed."""
     cfg, model, params = _build()
     long_n, mid_n, short_n = 512, 72, 16   # chunk 64: 8x, 1x+8, sub
     kw = dict(max_len=576, n_slots=4, chunk_steps=2, kv_page_size=16,
@@ -105,13 +132,7 @@ def test_chunked_prefill_improves_short_ttft_and_keeps_tokens():
     mono = ContinuousBatchingEngine(model, params, **kw)
     chunked = ContinuousBatchingEngine(model, params,
                                        prefill_chunk_tokens=64, **kw)
-    for eng in (mono, chunked):
-        # compile every prompt shape + a decode chunk off the clock
-        eng.serve([_req(90, long_n, 2, seed_off=500),
-                   _req(91, short_n, 2, seed_off=500),
-                   _req(92, mid_n, 2, seed_off=500)],
-                  honor_arrivals=False)
-    t_long = min(_timed_serve(mono, long_n) for _ in range(2))
+    t_long = TOKEN_S * long_n        # the long's monolithic prefill
 
     def trace():
         return ([_req(0, long_n, 4, arrival_s=0.0)]
@@ -122,7 +143,8 @@ def test_chunked_prefill_improves_short_ttft_and_keeps_tokens():
 
     outs, worst_short_ttft = {}, {}
     for name, eng in [("mono", mono), ("chunked", chunked)]:
-        done = eng.serve(trace())
+        clock = TokenClock(eng)
+        done = eng.serve(trace(), now=clock.now, sleep=clock.sleep)
         outs[name] = _by_rid(done)
         worst_short_ttft[name] = max(
             r.first_token_s - r.arrival_s for r in done
